@@ -63,6 +63,7 @@ from repro.analysis import audit
 from repro.core import fft as tfft
 from repro.core.fft import distributed as dist
 from repro.core.fft import spectral as spec
+from repro.launch.mesh import make_mesh
 
 from .common import emit, fft_gflops, timeit
 
@@ -76,7 +77,7 @@ def _check(tag, fn, args, model, **kw) -> dict:
 # the per-kind model keys check_cell diffs — a forward+inverse pair
 # pipeline (fft_convolve round trip) is modeled by summing both directions
 _PAIR_KEYS = ("all_to_all_count", "all_gather_count", "all_to_all_bytes",
-              "gather_hlo", "psum_hlo", "permute_hlo", "hlo_bytes",
+              "gather_hlo", "psum_hlo", "hlo_bytes",
               "total_wire")
 
 
@@ -97,7 +98,7 @@ def run(smoke: bool = True):
         print("# fft_distributed: single device visible — skipping "
               "(set --xla_force_host_platform_device_count)")
         return []
-    mesh = jax.make_mesh((shards,), ("fft",))
+    mesh = make_mesh((shards,), ("fft",))
     rng = np.random.default_rng(0)
     rows = []
     for ln, b in grid(smoke):
@@ -215,7 +216,7 @@ def run_multidim(smoke: bool = True):
         return []
     from repro.core.fft import multidim as md
 
-    mesh = jax.make_mesh((shards,), ("fft",))
+    mesh = make_mesh((shards,), ("fft",))
     rng = np.random.default_rng(2)
     rows = []
     for rr, cc, b in [(128, 256, 8)] if smoke else [(128, 256, 8),
@@ -261,7 +262,7 @@ def run_multidim(smoke: bool = True):
             f"{tagp}:conv2", md._conv2_pair_fn(mesh, "fft", None),
             (x, vk), model_cv, dtype="complex64"), model_cv))
         if len(jax.devices()) >= 4:
-            mesh2 = jax.make_mesh((2, 2), ("data", "fft"))
+            mesh2 = make_mesh((2, 2), ("data", "fft"))
             for nat in (False, True):
                 mdl_p = md.collective_volume_nd(
                     (rr, cc), b, 2, decomp="pencil", data_shards=2,
@@ -297,7 +298,7 @@ def run_mesh2d(smoke: bool = True):
     if len(jax.devices()) < 4:
         print("# fft_distributed 2-D: needs 4 devices — skipping")
         return []
-    mesh = jax.make_mesh((2, 2), ("data", "fft"))
+    mesh = make_mesh((2, 2), ("data", "fft"))
     rng = np.random.default_rng(1)
     rows = []
     for ln, b, g in [(14, 8, 4)] if smoke else [(14, 8, 4), (17, 16, 8)]:
@@ -345,7 +346,7 @@ def run_plan_reuse(smoke: bool = True):
     if shards < 2:
         print("# fft_plan_reuse: single device visible — skipping")
         return []
-    mesh = jax.make_mesh((shards,), ("fft",))
+    mesh = make_mesh((shards,), ("fft",))
     rng = np.random.default_rng(3)
     rows = []
     # small N so wall clock is dispatch-dominated (the quantity under
@@ -443,7 +444,7 @@ def run_overlap(smoke: bool = True):
     if shards < 2:
         print("# fft_overlap: single device visible — skipping")
         return []
-    mesh = jax.make_mesh((shards,), ("fft",))
+    mesh = make_mesh((shards,), ("fft",))
     rng = np.random.default_rng(5)
     rows = []
     for ln, b in [(14, 8)] if smoke else [(14, 8), (17, 16)]:
@@ -547,7 +548,7 @@ def run_real(smoke: bool = True):
         return []
     from repro.core.fft import multidim as md
 
-    mesh = jax.make_mesh((shards,), ("fft",))
+    mesh = make_mesh((shards,), ("fft",))
     rng = np.random.default_rng(4)
     rows = []
     for rr, cc, b in [(128, 256, 8)] if smoke else [(128, 256, 8),

@@ -38,6 +38,7 @@ import numpy as np
 import jax
 
 from repro.core.ft.injection import FaultSchedule
+from repro.launch.mesh import make_mesh
 from repro.serve import (Fault, QueueFullError, RuntimeConfig, ServeRuntime,
                          percentiles)
 
@@ -49,7 +50,7 @@ def _mesh():
     shards = 1 << (ndev.bit_length() - 1)
     if shards < 2:
         return None
-    return jax.make_mesh((shards,), ("fft",))
+    return make_mesh((shards,), ("fft",))
 
 
 def _request_pool(rng, smoke: bool):

@@ -42,6 +42,7 @@ from repro.core.fft import spectral as spectral_mod
 from repro.core.fft.api import FFTSpec
 from repro.core.gemm.api import GEMMSpec
 from repro.core.plan import FTConfig, plan as build_plan
+from repro.launch.mesh import make_mesh
 
 __all__ = ["AuditError", "Finding", "CellReport", "AuditReport",
            "measure", "check_cell", "audit_plan", "audit_specs",
@@ -150,15 +151,13 @@ def _diff(tag, ops, root, model, *, rtol, check_exposed, dtype=None):
             bad("unexpected-collective",
                 f"{count['reduce-scatter']} reduce-scatter op(s); no "
                 f"pipeline models any")
-        if count["collective-permute"] and not model.get("permute_hlo"):
-            # only the batch-sharded ft stats extraction permutes
+        if count["collective-permute"]:
             bad("unexpected-collective",
                 f"{count['collective-permute']} collective-permute op(s); "
-                f"model carries no permute term")
+                f"no pipeline models any")
         for kind, key in (("all-to-all", "all_to_all_bytes"),
                           ("all-gather", "gather_hlo"),
-                          ("all-reduce", "psum_hlo"),
-                          ("collective-permute", "permute_hlo")):
+                          ("all-reduce", "psum_hlo")):
             if key in model and _rel_off(by_kind[kind], model[key], rtol):
                 bad(f"{kind}-bytes",
                     f"hlo={by_kind[kind]:.0f}B model={model[key]:.0f}B")
@@ -393,7 +392,7 @@ def default_meshes(ndev: int | None = None):
                         ((2, 2), ("data", "fft")),
                         ((2, 4), ("data", "fft"))):
         if int(np.prod(shape)) <= ndev:
-            out.append(jax.make_mesh(shape, axes))
+            out.append(make_mesh(shape, axes))
     return out
 
 

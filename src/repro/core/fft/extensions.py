@@ -20,6 +20,8 @@ which is a direct DFT (odd lengths are outside the power-of-two planner).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -70,19 +72,30 @@ def rfft(x: jax.Array, *, mesh=None, axis: str = FFT_AXIS,
         # odd n: no even/odd split — direct DFT, cropped half spectrum
         full = naive_dft(x.astype(_complex_for(x.dtype)))
         return full[..., :n // 2 + 1]
-    half = n // 2
-    # pack: z[k] = x[2k] + i x[2k+1]; one half-length C2C transform
-    z = x[..., 0::2] + 1j * x[..., 1::2]
-    z = z.astype(_complex_for(x.dtype))
+    z = pack_half(x)
     p = _plan_c2c(z, mesh, axis, data_axis)
     zf = p.fft(z) if p is not None else _fft(z)
-    k = jnp.arange(half + 1)
-    w = jnp.exp(-2j * np.pi * k / n).astype(zf.dtype)
+    return unpack_half(zf, n)
+
+
+# The packing trick's two elementwise halves are jitted: run eagerly under
+# x64, their Python scalars would reach the device as 64-bit (complex128)
+# operands, which the TPU compiler cannot lower.
+@jax.jit
+def pack_half(x: jax.Array) -> jax.Array:
+    """(..., N) real -> (..., N/2) complex ``z[k] = x[2k] + i x[2k+1]``."""
+    return (x[..., 0::2] + 1j * x[..., 1::2]).astype(_complex_for(x.dtype))
+
+
+@functools.partial(jax.jit, static_argnames="n")
+def unpack_half(zf: jax.Array, n: int) -> jax.Array:
+    """Hermitian unpack: the (..., N/2) C2C bins of :func:`pack_half`'s
+    ``z`` -> the (..., N/2+1) rfft bins of the length-``n`` real signal."""
+    # host-side table: an on-device exp of int64 iotas (x64) is complex128
+    w = jnp.asarray(np.exp(-2j * np.pi * np.arange(n // 2 + 1) / n), zf.dtype)
     zf_ext = jnp.concatenate([zf, zf[..., :1]], axis=-1)      # Z[half] = Z[0]
     zconj = jnp.conj(zf_ext[..., ::-1])                        # Z*[half-k]
-    even = 0.5 * (zf_ext + zconj)
-    odd = -0.5j * (zf_ext - zconj)
-    return even + w * odd
+    return 0.5 * (zf_ext + zconj) - 0.5j * w * (zf_ext - zconj)
 
 
 def irfft(y: jax.Array, n: int | None = None, *, mesh=None,

@@ -13,9 +13,7 @@ import numpy as np
 
 __all__ = [
     "dft_matrix",
-    "dft_matrix_ri",
     "stage_twiddle",
-    "stage_twiddle_ri",
     "wang_encoding",
     "ones_encoding",
     "location_encoding",
@@ -39,12 +37,6 @@ def dft_matrix(n: int, *, inverse: bool = False) -> np.ndarray:
     return np.cos(ang) + 1j * np.sin(ang)
 
 
-def dft_matrix_ri(n: int, dtype=np.float32, *, inverse: bool = False):
-    """DFT matrix as a (real, imag) pair of real arrays (Pallas-friendly)."""
-    w = dft_matrix(n, inverse=inverse)
-    return w.real.astype(dtype), w.imag.astype(dtype)
-
-
 @functools.lru_cache(maxsize=None)
 def stage_twiddle(r: int, m: int, *, inverse: bool = False) -> np.ndarray:
     """Stage twiddle table T[k1, n2] = exp(-2*pi*i*k1*n2/(r*m)), shape (r, m).
@@ -60,11 +52,6 @@ def stage_twiddle(r: int, m: int, *, inverse: bool = False) -> np.ndarray:
     n2 = np.arange(m)[None, :]
     ang = sign * 2.0 * np.pi * ((k1 * n2) % n) / n
     return np.cos(ang) + 1j * np.sin(ang)
-
-
-def stage_twiddle_ri(r: int, m: int, dtype=np.float32, *, inverse: bool = False):
-    t = stage_twiddle(r, m, inverse=inverse)
-    return t.real.astype(dtype), t.imag.astype(dtype)
 
 
 # ---------------------------------------------------------------------------

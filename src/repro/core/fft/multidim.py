@@ -55,13 +55,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from . import factors
 from .distributed import (_AUTO, EPS, FFT_AXIS, DistFFTResult,
                           _grouped_verdict, _local_fft, _resolve_data_axis,
                           _resolve_mesh, _splice_recomputed, make_dist_plan,
                           resolve_abft_groups, resolve_chunks)
+from .extensions import pack_half, unpack_half
 from .stockham import naive_dft
 
 __all__ = [
@@ -231,21 +232,13 @@ def collective_volume_nd(shape: tuple[int, ...], batch: int, fft_shards: int,
         a2a_hlo = rows * grid * itemsize / d
         a2a_wire = a2a_hlo * (d - 1) / d
         # per-group verdict scalars + one energy scalar, plus the stats
-        # extraction: grouped pipelines broadcast ONE stacked (G/dd, 5)-
-        # real block, the ungrouped pipeline reduces its native scalars
-        # (3 predicates + score real + s32 location) — same structure the
-        # 1-D model counts; the plan auditor's per-kind psum diff pinned
-        # both terms down here too
+        # extraction: ONE stacked (G/dd, 5)-real block broadcast, G = 1
+        # included — same structure the 1-D model counts; the plan
+        # auditor's per-kind psum diff pinned both terms down here too
         verdict = (3 * groups // dd + 1) * (itemsize // 2)
-        stats = (5 * groups // dd * (itemsize // 2) if groups > 1
-                 else 3 + (itemsize // 2) + 4)
+        stats = 5 * groups // dd * (itemsize // 2)
         psum_hlo = 2.0 * (verdict + stats) if ft else 0.0
         psum_wire = psum_hlo * (d - 1) / d
-        # stats extraction on a batch-sharded mesh: one data-axis
-        # collective-permute of the 5*groups/dd-real block (see the 1-D
-        # model)
-        permute_hlo = (5 * groups // dd * (itemsize // 2)
-                       if ft and dd > 1 else 0.0)
         gather_hlo = gather_wire = 0.0
         a2a_count, gather_count = 1, 0
         local_bytes = rows * grid * itemsize / d
@@ -260,7 +253,7 @@ def collective_volume_nd(shape: tuple[int, ...], batch: int, fft_shards: int,
         a2a_wire = local * (d - 1) / d
         if dd > 1:
             a2a_wire += local * (dd - 1) / dd
-        psum_hlo = psum_wire = permute_hlo = 0.0
+        psum_hlo = psum_wire = 0.0
         full = float(batch * grid * itemsize)
         if natural_order:
             gather_hlo = full + (full / dd if dd > 1 else 0.0)
@@ -289,9 +282,8 @@ def collective_volume_nd(shape: tuple[int, ...], batch: int, fft_shards: int,
         "gather_wire": gather_wire,
         "psum_hlo": psum_hlo,
         "psum_wire": psum_wire,
-        "permute_hlo": permute_hlo,
-        "total_wire": a2a_wire + gather_wire + psum_wire + permute_hlo,
-        "hlo_bytes": a2a_hlo + gather_hlo + psum_hlo + permute_hlo,
+        "total_wire": a2a_wire + gather_wire + psum_wire,
+        "hlo_bytes": a2a_hlo + gather_hlo + psum_hlo,
         "local_bytes": local_bytes,
         "abft_overhead": 2.0 * groups / batch if (ft and batch) else 0.0,
     }
@@ -395,7 +387,7 @@ def _slab_fftn_fn(mesh: Mesh, axis: str, ndim: int, inverse: bool,
         in_spec[shard_pos[0]] = axis
         out_spec[shard_pos[1]] = axis
         out = shard_map(body, mesh=mesh, in_specs=P(*in_spec),
-                        out_specs=P(*out_spec), check_rep=False)(z)
+                        out_specs=P(*out_spec), check_vma=False)(z)
         return out.reshape(shape)
 
     return run
@@ -539,7 +531,7 @@ def _pencil_fftn_fn(mesh: Mesh, axis: str, ndim: int, inverse: bool,
         in_spec, out_spec = ((spec_t, spec_in) if inverse
                              else (spec_in, spec_t))
         out = shard_map(body, mesh=mesh, in_specs=P(*in_spec),
-                        out_specs=P(*out_spec), check_rep=False)(z)
+                        out_specs=P(*out_spec), check_vma=False)(z)
         if inverse or not natural_order:
             return out.reshape(shape)
         # digit restore to natural order: (k1, k2) -> (k2, k1) per
@@ -582,25 +574,14 @@ def _complex_of(dtype) -> jnp.dtype:
                      else jnp.complex64)
 
 
-def _unpack_half(zf: jax.Array, cc: int) -> jax.Array:
-    """Hermitian unpack of the packed half-length spectrum: (..., C/2)
-    C2C bins of z = x_even + i*x_odd -> the (..., C/2+1) rfft bins."""
-    half = cc // 2
-    k = jnp.arange(half + 1)
-    w = jnp.exp(-2j * np.pi * k / cc).astype(zf.dtype)
-    zf_ext = jnp.concatenate([zf, zf[..., :1]], axis=-1)      # Z[half] = Z[0]
-    zconj = jnp.conj(zf_ext[..., ::-1])                        # Z*[half-k]
-    return 0.5 * (zf_ext + zconj) - 0.5j * w * (zf_ext - zconj)
-
-
 def _rfft_cols(x: jax.Array) -> jax.Array:
     """Packed rfft over the (even-length) last axis: (..., C) real ->
     (..., C/2+1) half spectrum, via one half-length C2C transform."""
-    cc = x.shape[-1]
-    z = (x[..., 0::2] + 1j * x[..., 1::2]).astype(_complex_of(x.dtype))
-    return _unpack_half(_local_axis_fft(z, -1, inverse=False), cc)
+    return unpack_half(_local_axis_fft(pack_half(x), -1, inverse=False),
+                       x.shape[-1])
 
 
+@jax.jit   # not eager: see extensions.pack_half (no complex128 operands)
 def _irfft_cols(y: jax.Array) -> jax.Array:
     """Inverse of :func:`_rfft_cols` (normalized): (..., C/2+1) half
     spectrum -> (..., C) real, C = 2*(bins-1). Recovers the packed
@@ -608,8 +589,7 @@ def _irfft_cols(y: jax.Array) -> jax.Array:
     even/odd split, then interleaves its real and imaginary parts."""
     half = y.shape[-1] - 1
     cc = 2 * half
-    k = jnp.arange(half)
-    winv = jnp.exp(2j * np.pi * k / cc).astype(y.dtype)
+    winv = jnp.asarray(np.exp(2j * np.pi * np.arange(half) / cc), y.dtype)
     yh = y[..., :half]
     ymir = jnp.conj(y[..., 1:][..., ::-1])                     # Y*[half-k]
     e = 0.5 * (yh + ymir)
@@ -679,7 +659,7 @@ def _rslab_fft2_fn(mesh: Mesh, axis: str, data_axis: str | None = None):
 
         out = shard_map(body, mesh=mesh, in_specs=P(bspec, axis, None),
                         out_specs=P(bspec, None, axis),
-                        check_rep=False)(z)
+                        check_vma=False)(z)
         return out.reshape(shape[:-2] + (rr, cp))
 
     return run
@@ -712,7 +692,7 @@ def _rslab_ifft2_fn(mesh: Mesh, axis: str, data_axis: str | None = None):
 
         out = shard_map(body, mesh=mesh, in_specs=P(bspec, None, axis),
                         out_specs=P(bspec, axis, None),
-                        check_rep=False)(z)
+                        check_vma=False)(z)
         return out.reshape(shape[:-2] + (rr, cc))
 
     return run
@@ -1026,7 +1006,7 @@ def _ft_slab_fft2_fn(mesh: Mesh, axis: str, threshold: float, correct: bool,
             in_specs=P(bspec, axis, None),
             out_specs=(P(bspec, None, axis), P(bspec, axis),
                        P(axis, bspec, None)),
-            check_rep=False)(x)
+            check_vma=False)(x)
         st = stats[0]                # (G, 5); fft shards agree post-psum
         flagged = st[:, 1] > 0.5
         correctable = st[:, 3] > 0.5
@@ -1156,7 +1136,6 @@ def _ft_rslab_fft2_fn(mesh: Mesh, axis: str, threshold: float, correct: bool,
         bl, gl = b // dloc, g // dloc
         rl = rr // shards                    # local R rows in pass 1
         ftype = np.float64 if x.dtype == jnp.float64 else np.float32
-        ctype = jnp.complex128 if x.dtype == jnp.float64 else jnp.complex64
         ids = jnp.arange(1, s + 1, dtype=ftype)[None, :, None, None]
 
         def body(zl):
@@ -1169,12 +1148,12 @@ def _ft_rslab_fft2_fn(mesh: Mesh, axis: str, threshold: float, correct: bool,
             cs3_in = jnp.sum(ids * zg, axis=1)
             zc = jnp.concatenate([zl, cs2_in, cs3_in], axis=0)
             # ---- pass 1: packed half-length FFT over C + left checksum ----
-            zpk = (zc[..., 0::2] + 1j * zc[..., 1::2]).astype(ctype)
+            zpk = pack_half(zc)
             zf = _local_fft(zpk, False)
             res1 = jnp.abs(jnp.sum(zf, axis=-1) - half * zpk[..., 0])
             scale1 = jnp.sqrt(jnp.mean(jnp.abs(zpk) ** 2, axis=-1)) + EPS
             delta = jnp.max(res1 / (float(np.sqrt(half)) * scale1))
-            hc = _unpack_half(zf, cc)                # (bl+2gl, rl, C/2+1)
+            hc = unpack_half(zf, cc)                 # (bl+2gl, rl, C/2+1)
             hc = jnp.pad(hc, ((0, 0), (0, 0), (0, shards - 1)))
             # ---- fault injection (tests/benchmarks): one SEU per row
             # [fft_device, signal, local_r, col, enable, eps_re, eps_im]
@@ -1233,7 +1212,7 @@ def _ft_rslab_fft2_fn(mesh: Mesh, axis: str, threshold: float, correct: bool,
             in_specs=P(bspec, axis, None),
             out_specs=(P(bspec, None, axis), P(bspec, axis),
                        P(axis, bspec, None)),
-            check_rep=False)(x)
+            check_vma=False)(x)
         st = stats[0]                # (G, 5); fft shards agree post-psum
         flagged = st[:, 1] > 0.5
         correctable = st[:, 3] > 0.5
@@ -1363,7 +1342,7 @@ def _conv2_pair_fn(mesh: Mesh, axis: str, data_axis: str | None):
             body, mesh=mesh,
             in_specs=(P(bspec, axis, None), P(vspec, axis, None)),
             out_specs=P(bspec, axis, None),
-            check_rep=False)(a, v)
+            check_vma=False)(a, v)
 
     return run
 
@@ -1410,7 +1389,7 @@ def _rconv2_pair_fn(mesh: Mesh, axis: str, data_axis: str | None):
             body, mesh=mesh,
             in_specs=(P(bspec, axis, None), P(vspec, axis, None)),
             out_specs=P(bspec, axis, None),
-            check_rep=False)(a, v)
+            check_vma=False)(a, v)
 
     return run
 

@@ -28,10 +28,15 @@ from typing import Sequence
 
 __all__ = ["Plan", "StagePlan", "make_plan", "block_radices", "PLAN_TABLE"]
 
-# VMEM working-set budget per kernel instance (bytes). TPU v5e VMEM is
-# ~128 MiB/core but leaving headroom for double-buffering and checksum
-# scratch; the tuner targets <= 8 MiB resident per block.
+# VMEM budget for a kernel's pipelined blocks (bytes). A v5e core has
+# 128 MiB of VMEM (the allocation limit the TPU compiler reports for a
+# described v5e), but a Pallas kernel is held to the compiler's default
+# *scoped* limit of 16 MiB. Half of that goes to the blocks, leaving the
+# rest for the chunk temporaries, constant tables and checksum scratch.
 VMEM_BUDGET = 8 * 1024 * 1024
+
+# TPU vreg lane width: the kernels' minor dimension (see kernels/stockham.py)
+LANES = 128
 
 # Largest signal length executed in a single VMEM-resident block FFT.
 MAX_BLOCK_N = 1 << 13  # 8192 complex64 = 64 KiB per signal
@@ -134,9 +139,10 @@ def _split_kernel_factors(n: int) -> tuple[int, ...]:
 
 
 def _pick_bs(n_block: int, batch: int, itemsize: int) -> int:
-    """Signals per grid tile: fill VMEM budget, stay lane-aligned."""
-    # Working set ~= 3 buffers (in, out, twiddle/scratch) of bs * n complex.
-    per_signal = 3 * 2 * itemsize * n_block
+    """Signals per grid tile: fill the VMEM budget, stay sublane-aligned."""
+    # Per signal, the pipeline holds re/im x in/out blocks, each double-
+    # buffered; a signal shorter than the lane width still fills 128 lanes.
+    per_signal = 2 * 2 * 2 * itemsize * max(n_block, LANES)
     bs = max(1, VMEM_BUDGET // max(per_signal, 1))
     # lane alignment: prefer multiples of 8 (sublane) once available
     if bs >= 8:

@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from . import factors
 from .distributed import (_AUTO, FFT_AXIS, _local_fft, _pad_batch_rows,
@@ -207,7 +207,7 @@ def _spectral_pair_fn(mesh: Mesh, axis: str, data_axis: str | None,
             body, mesh=mesh,
             in_specs=(P(bspec, None, axis), P(vspec, None, axis)),
             out_specs=P((bspec, axis) if bspec else axis, None),
-            check_rep=False)(za, zv)
+            check_vma=False)(za, zv)
         return out
 
     return run
@@ -288,7 +288,7 @@ def _spectral_real_fn(mesh: Mesh, axis: str, data_axis: str | None,
             body, mesh=mesh,
             in_specs=(P(bspec, None, axis),),
             out_specs=P((bspec, axis) if bspec else axis, None),
-            check_rep=False)(zp)
+            check_vma=False)(zp)
         return out
 
     return run

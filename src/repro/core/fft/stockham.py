@@ -26,6 +26,10 @@ from .plan import Plan, make_plan
 __all__ = ["fft", "ifft", "fft_with_plan", "block_fft_stages", "naive_dft",
            "radix2_fft"]
 
+# A TPU runs a DEFAULT-precision f32 matmul as one bf16 pass, too coarse for
+# the 4e-5 spectrum tolerance; ask for full f32 (a no-op on the CPU).
+_PRECISION = jax.lax.Precision.HIGHEST
+
 
 def _factor_const(r: int, dtype, inverse: bool):
     return jnp.asarray(factors.dft_matrix(r, inverse=inverse), dtype=dtype)
@@ -60,7 +64,7 @@ def _fft_recursive(x: jax.Array, stages, inverse: bool) -> jax.Array:
     dtype = x.dtype
     z = x.reshape(x.shape[:-1] + (r, m))
     w = _factor_const(r, dtype, inverse)
-    z = jnp.einsum("kr,...rm->...km", w, z)
+    z = jnp.einsum("kr,...rm->...km", w, z, precision=_PRECISION)
     if m > 1:
         z = z * _twiddle_const(r, m, dtype, inverse)
         z = _fft_recursive(z, stages[1:], inverse)  # FFT along last axis (m)
@@ -116,7 +120,7 @@ def naive_dft(x: jax.Array, *, inverse: bool = False) -> jax.Array:
     """O(N^2) direct DFT — the paper's conceptual v0 lower bound."""
     n = x.shape[-1]
     w = jnp.asarray(factors.dft_matrix(n, inverse=inverse), dtype=x.dtype)
-    y = jnp.einsum("kn,...n->...k", w, x)
+    y = jnp.einsum("kn,...n->...k", w, x, precision=_PRECISION)
     return y / n if inverse else y
 
 

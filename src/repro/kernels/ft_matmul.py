@@ -33,6 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ._platform import resolve_interpret
+
 __all__ = ["ft_matmul_pallas", "FTMatmulChecks"]
 
 
@@ -80,16 +82,19 @@ def _kernel(nm, nk, bm, bn, nf, x_ref, w_ref, xsum_ref, xloc_ref, inj_ref,
         c = acc_ref[...]
         # in-kernel SEU injection: lands in the computed product BEFORE the
         # output strips accumulate — exactly what the scheme must detect
-        rows = m_i * bm + jax.lax.broadcasted_iota(jnp.float32, (bm, bn), 0)
-        cols = n_i * bn + jax.lax.broadcasted_iota(jnp.float32, (bm, bn), 1)
+        # Mosaic builds integer iotas only: index in int32, then cast
+        rows = (m_i * bm + jax.lax.broadcasted_iota(
+            jnp.int32, (bm, bn), 0)).astype(jnp.float32)
+        cols = (n_i * bn + jax.lax.broadcasted_iota(
+            jnp.int32, (bm, bn), 1)).astype(jnp.float32)
         inj = inj_ref[...]
         for f in range(nf):
             hit = (rows == inj[f, 0]) & (cols == inj[f, 1])
             c = c + jnp.where(hit, inj[f, 2] * inj[f, 3], 0.0)
         c_ref[...] = c.astype(c_ref.dtype)
         col_acc[...] += jnp.sum(c, axis=0, keepdims=True)
-        loc = (m_i * bm + 1.0
-               + jax.lax.broadcasted_iota(jnp.float32, (bm, 1), 0))
+        loc = (m_i * bm + 1 + jax.lax.broadcasted_iota(
+            jnp.int32, (bm, 1), 0)).astype(jnp.float32)
         row_acc[...] += jnp.sum(c * loc, axis=0, keepdims=True)
 
     @pl.when((k_i == nk - 1) & (m_i == nm - 1))
@@ -112,16 +117,18 @@ def _ft_matmul_pallas(x, w, inj, *, bm, bn, bk, interpret):
 
     grid = (nn, nm, nk)
     kernel = functools.partial(_kernel, nm, nk, bm, bn, inj.shape[0])
-    strip = pl.BlockSpec((1, bn), lambda ni, mi, ki: (0, ni))
+    # int32 block indices throughout: the kernel must compile with x64 on
+    zero = lambda: jnp.int32(0)  # noqa: E731
+    strip = pl.BlockSpec((1, bn), lambda ni, mi, ki: (zero(), ni))
     c, out2, pred2, out3, pred3 = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda ni, mi, ki: (mi, ki)),
             pl.BlockSpec((bk, bn), lambda ni, mi, ki: (ki, ni)),
-            pl.BlockSpec((1, bk), lambda ni, mi, ki: (0, ki)),
-            pl.BlockSpec((1, bk), lambda ni, mi, ki: (0, ki)),
-            pl.BlockSpec(inj.shape, lambda ni, mi, ki: (0, 0)),
+            pl.BlockSpec((1, bk), lambda ni, mi, ki: (zero(), ki)),
+            pl.BlockSpec((1, bk), lambda ni, mi, ki: (zero(), ki)),
+            pl.BlockSpec(inj.shape, lambda ni, mi, ki: (zero(), zero())),
         ],
         out_specs=[
             pl.BlockSpec((bm, bn), lambda ni, mi, ki: (mi, ni)),
@@ -172,11 +179,9 @@ def ft_matmul_pallas(x, w, *, bm=128, bn=128, bk=128,
             f"({m}, {k}, {n}) vs tiles (bm, bk, bn)=({bm}, {bk}, {bn}) — "
             f"pad the operands or use the interpreter path "
             f"(core.abft.gemm.ft_matmul)")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     if inject is None:
         inj = jnp.zeros((1, 4), jnp.float32)
     else:
         inj = jnp.reshape(jnp.asarray(inject, jnp.float32), (-1, 4))
     return _ft_matmul_pallas(x, w, inj, bm=bm, bn=bn, bk=bk,
-                             interpret=bool(interpret))
+                             interpret=resolve_interpret(interpret))

@@ -7,8 +7,9 @@
   detect -> locate -> delayed batched correction. Returns an
   :class:`FTFFTResult` with the corrected outputs and the FT telemetry.
 
-On CPU (this container) kernels default to interpret mode; on TPU they
-compile natively. ``interpret=None`` auto-detects.
+``interpret=None`` resolves by platform: the kernels compile with Mosaic on
+a TPU and run in the Pallas interpreter elsewhere (the CPU test suite).
+``tests/test_tpu_compile.py`` compiles them for a described v5e.
 """
 from __future__ import annotations
 
@@ -25,6 +26,7 @@ from repro.core.fft import factors as fft_factors
 from repro.core.fft.plan import Plan, make_plan
 from repro.core.fft.stockham import block_fft_stages
 
+from ._platform import resolve_interpret
 from .stockham import block_fft_pallas
 from .stockham_abft import abft_fft_pallas
 
@@ -55,12 +57,6 @@ def _resolve_legacy(entry: str, kw: dict) -> dict:
     if deprecated:
         fft_api.warn_deprecated_kwargs(f"kernels.ops.{entry}", deprecated)
     return out
-
-
-def _auto_interpret(interpret):
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
 
 
 def _split(x):
@@ -126,7 +122,7 @@ def _fft_multipass(x2d, plan: Plan, *, inverse, interpret):
 
 @functools.partial(jax.jit, static_argnames=("inverse", "interpret"))
 def _fft_impl(x, *, inverse=False, interpret=None):
-    interpret = _auto_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     shape = x.shape
     n = shape[-1]
     x2d = x.reshape((-1, n))
@@ -134,9 +130,8 @@ def _fft_impl(x, *, inverse=False, interpret=None):
     if plan.num_passes == 1:
         y = _block_fft_c(x2d, inverse=inverse, interpret=interpret)
     else:
+        # each pass's block kernel applies its own 1/f_i; prod(f_i) = n
         y = _fft_multipass(x2d, plan, inverse=inverse, interpret=interpret)
-        if inverse:
-            y = y / n
     return y.reshape(shape)
 
 
@@ -316,7 +311,7 @@ def _ft_fft_local(
     inject: jax.Array | None = None,
 ) -> FTFFTResult:
     """The single-device fused-kernel pipeline behind :func:`ft_fft`."""
-    interpret = _auto_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     b, n = x.shape
     xr, xi = _split(x)
     plan = make_plan(n, batch=b, itemsize=xr.dtype.itemsize)

@@ -15,11 +15,16 @@ the paper's multi-transaction threadblock. Checksums are *fused*:
 An optional in-kernel SEU injector corrupts one output element of one tile —
 simulating a transient compute-unit fault *inside* the protected region, so
 tests exercise true end-to-end detect->locate->correct.
+
+The tile is the block kernel's ``(bs * R, L)`` layout (kernels/stockham.py):
+rows ``(signal, n1)`` on input and ``(signal, k1)`` on output. The group
+checksums sum rows with equal ``n1`` (``k1``) as a selection matmul per
+chunk; the per-signal sums come out per row and are added over each
+signal's R rows outside the kernel.
 """
 from __future__ import annotations
 
 import functools
-from typing import Sequence
 
 import jax
 import jax.numpy as jnp
@@ -30,85 +35,81 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.abft.encoding import EPS, left_encoding, left_encoding_image
 from repro.core.fft.plan import Plan, make_plan
 
-from .stockham import fft_stages_value, stage_consts
+from ._platform import resolve_interpret
+from .stockham import (block_layout, chunk_rows, const_specs, fft_chunk,
+                       for_each_chunk, from_blocks, layout_consts, mxu_dot,
+                       to_blocks)
 
 __all__ = ["abft_fft_pallas"]
 
 
-def _abft_kernel(stages, layout, n_const, bs, transactions, per_signal,
-                 # refs:
-                 xr_ref, xi_ref, ew_ref, e1_ref, inj_ref, *rest):
-    const_refs = rest[:n_const]
-    yr_ref, yi_ref, delta_ref, cs_ref = rest[n_const:n_const + 4]
-    acc_ref = rest[n_const + 4]
+def _abft_kernel(r, ch, transactions, per_signal, n_const,
+                 xr_ref, xi_ref, inj_i_ref, inj_f_ref, *rest):
+    enc = [c[...] for c in rest[:4]] if per_signal else None
+    rest = rest[4:] if per_signal else rest
+    consts = [c[...] for c in rest[:n_const]]
+    yr_ref, yi_ref = rest[n_const:n_const + 2]
+    part_ref = rest[n_const + 2] if per_signal else None
+    cs_ref, acc_ref = rest[-2:]
 
-    g = pl.program_id(0)
     t = pl.program_id(1)
-    tile = g * transactions + t
+    tile = pl.program_id(0) * transactions + t
+    rows, lanes = xr_ref.shape
+    ftype = xr_ref.dtype
 
     @pl.when(t == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    xr = xr_ref[...]
-    xi = xi_ref[...]
-    ftype = xr.dtype
+    # selection rows for the group checksums: sel[q, j] = 1 where chunk row
+    # j holds n1 (or k1) == q; padded to a full sublane tile when R < 8
+    rp = acc_ref.shape[1]
+    q_io = jax.lax.broadcasted_iota(jnp.int32, (rp, ch), 0)
+    j_io = jax.lax.broadcasted_iota(jnp.int32, (rp, ch), 1)
+    sel = ((j_io & (r - 1)) == q_io).astype(ftype)
+    row_io = jax.lax.broadcasted_iota(jnp.int32, (ch, lanes), 0)
+    lane_io = jax.lax.broadcasted_iota(jnp.int32, (ch, lanes), 1)
+    shift = r.bit_length() - 1
 
-    # ---- left-side input checksum: s_in[b] = sum_n (e1^T W)[n] * x[b, n]
-    if per_signal:
-        ewr = ew_ref[0, :]
-        ewi = ew_ref[1, :]
-        s_in_r = xr @ ewr - xi @ ewi
-        s_in_i = xr @ ewi + xi @ ewr
+    def body(r0):
+        sl = pl.ds(r0, ch)
+        xr = xr_ref[sl, :]
+        xi = xi_ref[sl, :]
+        yr, yi = fft_chunk(xr, xi, consts)
 
-    # ---- the FFT itself (all stages VMEM-resident, MXU contractions)
-    consts = [c[...] for c in const_refs]
-    yr, yi = fft_stages_value(xr, xi, stages, consts, layout)
+        # ---- simulated SEU at the compute units (inside the protected
+        # region): [row in the whole array, lane, enabled]
+        grow = tile * rows + r0
+        hit = ((inj_i_ref[0, 2] > 0) & (grow + row_io == inj_i_ref[0, 0])
+               & (lane_io == inj_i_ref[0, 1]))
+        yr = yr + jnp.where(hit, inj_f_ref[0, 0], 0).astype(ftype)
+        yi = yi + jnp.where(hit, inj_f_ref[0, 1], 0).astype(ftype)
+        yr_ref[sl, :] = yr
+        yi_ref[sl, :] = yi
 
-    # ---- simulated SEU at the compute units (inside the protected region)
-    inj = inj_ref[0, :]
-    n = yr.shape[-1]
-    row_iota = jax.lax.broadcasted_iota(jnp.int32, (bs, n), 0)
-    col_iota = jax.lax.broadcasted_iota(jnp.int32, (bs, n), 1)
-    hit = ((inj[3] > 0) & (inj[0].astype(jnp.int32) == tile)
-           & (row_iota == inj[1].astype(jnp.int32))
-           & (col_iota == inj[2].astype(jnp.int32)))
-    yr = yr + jnp.where(hit, inj[4].astype(ftype), 0).astype(ftype)
-    yi = yi + jnp.where(hit, inj[5].astype(ftype), 0).astype(ftype)
+        # ---- right-side checksums, accumulated across transactions.
+        # Location encoding: global 1-based signal id (paper: "each thread
+        # aggregates the product of its share and the global ID for the
+        # signal").
+        gid = (((grow + j_io) >> shift) + 1).astype(ftype)
+        selg = sel * gid
+        for q, (s, v) in enumerate(((sel, xr), (sel, xi), (selg, xr),
+                                    (selg, xi), (sel, yr), (sel, yi),
+                                    (selg, yr), (selg, yi))):
+            acc_ref[q] += mxu_dot(s, v)
 
-    # ---- left-side output checksum: s_out[b] = sum_k e1[k] * y[b, k]
-    if per_signal:
-        e1r = e1_ref[0, :]
-        e1i = e1_ref[1, :]
-        s_out_r = yr @ e1r - yi @ e1i
-        s_out_i = yr @ e1i + yi @ e1r
-        dr = s_in_r - s_out_r
-        di = s_in_i - s_out_i
-        mag = jnp.sqrt(s_in_r * s_in_r + s_in_i * s_in_i) + EPS
-        delta_ref[...] = (jnp.sqrt(dr * dr + di * di) / mag)[:, None]
-    else:
-        delta_ref[...] = jnp.zeros_like(delta_ref)
+        # ---- left-side per-row sums: s_in = (e1^T W) x, s_out = e1^T y
+        if per_signal:
+            ewr, ewi, e1r, e1i = enc
+            for q, p in enumerate((xr * ewr - xi * ewi, xr * ewi + xi * ewr,
+                                   yr * e1r - yi * e1i, yr * e1i + yi * e1r)):
+                part_ref[sl, q:q + 1] = jnp.sum(p, axis=1, keepdims=True)
 
-    # ---- right-side checksums, accumulated across transactions in scratch.
-    # Location encoding: global 1-based signal id (paper: "each thread
-    # aggregates the product of its share and the global ID for the signal").
-    gid = (tile * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
-           + 1).astype(ftype)
-    acc = acc_ref[...]
-    upd = jnp.stack([
-        jnp.sum(xr, axis=0), jnp.sum(xi, axis=0),
-        jnp.sum(gid * xr, axis=0), jnp.sum(gid * xi, axis=0),
-        jnp.sum(yr, axis=0), jnp.sum(yi, axis=0),
-        jnp.sum(gid * yr, axis=0), jnp.sum(gid * yi, axis=0),
-    ])
-    acc_ref[...] = acc + upd
-
-    yr_ref[...] = yr
-    yi_ref[...] = yi
+    for_each_chunk(rows, ch, body)
 
     @pl.when(t == transactions - 1)
     def _emit():
-        cs_ref[0, :, :] = acc_ref[...]
+        cs_ref[0] = acc_ref[...]
 
 
 @functools.partial(
@@ -126,7 +127,7 @@ def abft_fft_pallas(
     per_signal: bool = True,
     encoding: str = "wang",
     inverse: bool = False,
-    interpret: bool = True,
+    interpret: bool | None = None,
     inject: jax.Array | None = None,
 ):
     """Fused FT-FFT: returns (yr, yi, delta, cs).
@@ -137,6 +138,8 @@ def abft_fft_pallas(
       [x*e2 r/i, x*e3 r/i, y*e2 r/i, y*e3 r/i] per transaction group,
     * ``inject`` — optional (6,) array [tile, row, col, enabled, eps_r, eps_i]
       (float; integer fields rounded) simulating one SEU.
+
+    ``interpret=None`` compiles on a TPU and interprets elsewhere.
     """
     b, n = xr.shape
     if inverse:
@@ -144,13 +147,11 @@ def abft_fft_pallas(
             "ABFT protection covers the forward transform (paper scope); "
             "protect ifft by conjugation: ifft(x) = conj(fft(conj(x)))/n")
     if plan is None:
-        plan = make_plan(n, batch=b, itemsize=xr.dtype.itemsize,
-                         inverse=inverse)
+        plan = make_plan(n, batch=b, itemsize=xr.dtype.itemsize)
     if plan.num_passes != 1:
         raise ValueError(
             f"abft_fft_pallas is single-pass; got {plan.describe()} — "
             f"compose larger sizes at the JAX level (ops.ft_fft)")
-    stages = plan.stages[0]
     if bs is None:
         bs = min(plan.bs, b)
     if b % bs != 0:
@@ -161,49 +162,69 @@ def abft_fft_pallas(
                          f"divisible by transactions={transactions}")
     groups = tiles // transactions
 
+    r, lanes = block_layout(n)
+    rows = bs * r
+    ch = chunk_rows(rows)
+    rp = max(r, 8)
     np_dtype = np.float64 if xr.dtype == jnp.float64 else np.float32
-    consts, layout = stage_consts(stages, np_dtype, inverse=inverse)
-    const_arrays = [jnp.asarray(c) for c in consts]
+    consts = [jnp.asarray(c) for c in layout_consts(n, ch, np_dtype)]
+    if per_signal:
+        # e1^T W indexes inputs n = L*n1 + n2; e1 indexes outputs
+        # k = k1 + R*k2, i.e. the output tile's (k1, k2) layout
+        ew = left_encoding_image(n, encoding).reshape(r, lanes)
+        e1 = left_encoding(n, encoding).reshape(lanes, r).T
+        enc = [jnp.asarray(np.tile(v, (ch // r, 1)).astype(np_dtype))
+               for v in (ew.real, ew.imag, e1.real, e1.imag)]
+    else:
+        enc = []
 
-    ew = left_encoding_image(n, encoding, inverse=inverse)
-    e1 = left_encoding(n, encoding)
-    ew_arr = jnp.asarray(
-        np.stack([ew.real, ew.imag]).astype(np_dtype))          # (2, N)
-    e1_arr = jnp.asarray(
-        np.stack([e1.real, e1.imag]).astype(np_dtype))          # (2, N)
     if inject is None:
         inject = jnp.full((6,), -1.0, dtype=jnp.float32)
-    inj_arr = jnp.reshape(inject.astype(np_dtype), (1, 6))
+    fields = jnp.round(inject[:4]).astype(jnp.int32)
+    tile, row, col, enabled = fields[0], fields[1], fields[2], fields[3]
+    inj_i = jnp.stack([(tile * bs + row) * r + col % r, col // r,
+                       enabled])[None]
+    inj_f = inject[4:6].astype(np_dtype)[None]
 
-    grid = (groups, transactions)
-    x_spec = pl.BlockSpec((bs, n), lambda g, t: (g * transactions + t, 0))
-    vec_spec = pl.BlockSpec((2, n), lambda g, t: (0, 0))
-    inj_spec = pl.BlockSpec((1, 6), lambda g, t: (0, 0))
-    const_specs = [
-        pl.BlockSpec(c.shape, lambda g, t, _nd=c.ndim: (0,) * _nd)
-        for c in const_arrays
-    ]
-    delta_spec = pl.BlockSpec((bs, 1), lambda g, t: (g * transactions + t, 0))
-    cs_spec = pl.BlockSpec((1, 8, n), lambda g, t: (g, 0, 0))
+    x_spec = pl.BlockSpec(
+        (rows, lanes), lambda g, t: (g * transactions + t, jnp.int32(0)))
+    out_specs = [x_spec, x_spec]
+    out_shape = [jax.ShapeDtypeStruct((b * r, lanes), xr.dtype)] * 2
+    if per_signal:
+        out_specs.append(pl.BlockSpec(
+            (rows, 4), lambda g, t: (g * transactions + t, jnp.int32(0))))
+        out_shape.append(jax.ShapeDtypeStruct((b * r, 4), xr.dtype))
+    out_specs.append(pl.BlockSpec((1, 8, rp, lanes),
+                                  lambda g, t: (g,) + (jnp.int32(0),) * 3))
+    out_shape.append(jax.ShapeDtypeStruct((groups, 8, rp, lanes), xr.dtype))
 
-    kernel = functools.partial(_abft_kernel, stages, layout,
-                               len(const_arrays), bs, transactions,
-                               per_signal)
-    yr, yi, delta, cs = pl.pallas_call(
+    kernel = functools.partial(_abft_kernel, r, ch, transactions, per_signal,
+                               len(consts))
+    outs = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[x_spec, x_spec, vec_spec, vec_spec, inj_spec] + const_specs,
-        out_specs=[x_spec, x_spec, delta_spec, cs_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, n), xr.dtype),
-            jax.ShapeDtypeStruct((b, n), xi.dtype),
-            jax.ShapeDtypeStruct((b, 1), xr.dtype),
-            jax.ShapeDtypeStruct((groups, 8, n), xr.dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((8, n), jnp.dtype(np_dtype))],
-        interpret=interpret,
-    )(xr, xi, ew_arr, e1_arr, inj_arr, *const_arrays)
-    if inverse:
-        scale = jnp.asarray(1.0 / n, dtype=xr.dtype)
-        yr, yi, cs = yr * scale, yi * scale, cs * scale
-    return yr, yi, delta[:, 0], cs
+        grid=(groups, transactions),
+        in_specs=([x_spec, x_spec]
+                  + const_specs([inj_i, inj_f], memory_space=pltpu.SMEM)
+                  + const_specs(enc + consts)),
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((8, rp, lanes), jnp.dtype(np_dtype))],
+        interpret=resolve_interpret(interpret),
+        name="abft_fft",
+    )(to_blocks(xr), to_blocks(xi), inj_i, inj_f, *enc, *consts)
+    yr, yi, cs = from_blocks(outs[0], n), from_blocks(outs[1], n), outs[-1]
+
+    # checksums back to natural order: input sums are (n1, n2) row-major,
+    # output sums (k1, k2) with k = k1 + R*k2
+    cs = cs[:, :, :r]
+    cs = jnp.concatenate(
+        [cs[:, :4].reshape(groups, 4, n),
+         jnp.swapaxes(cs[:, 4:], -1, -2).reshape(groups, 4, n)], axis=1)
+    if per_signal:
+        s = outs[2].reshape(b, r, 4).sum(axis=1)
+        dr, di = s[:, 0] - s[:, 2], s[:, 1] - s[:, 3]
+        mag = jnp.sqrt(s[:, 0] ** 2 + s[:, 1] ** 2) + EPS
+        delta = jnp.sqrt(dr * dr + di * di) / mag
+    else:
+        delta = jnp.zeros((b,), xr.dtype)
+    return yr, yi, delta, cs

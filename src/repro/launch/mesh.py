@@ -1,4 +1,10 @@
-"""Production mesh builders.
+"""Mesh builders: the one place a ``jax.sharding.Mesh`` is made.
+
+Every mesh here has ``Auto`` axes. ``jax.make_mesh`` defaults to
+``Explicit`` axes on JAX >= 0.7, whose sharding-in-types rules refuse the
+reshapes and slices the FFT pipelines apply around ``shard_map``
+(``ShardingTypeError: Splitting on more than 1 axis``); with ``Auto`` axes
+GSPMD propagates those shardings as it always has.
 
 ``make_production_mesh`` is a FUNCTION (not a module-level constant) so that
 importing this module never touches jax device state — the dry-run sets
@@ -7,15 +13,24 @@ XLA_FLAGS before first jax init and only then calls it.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_host_mesh", "make_fft_mesh"]
+__all__ = ["make_mesh", "make_production_mesh", "make_host_mesh",
+           "make_fft_mesh"]
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto`` (see the module doc)."""
+    shape, axes = tuple(shape), tuple(axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 (512 chips, 2 pods)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -23,7 +38,7 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     if data * model > n:
         data, model = n, 1
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def make_fft_mesh(shards: int | None = None, data: int = 1):
@@ -37,24 +52,24 @@ def make_fft_mesh(shards: int | None = None, data: int = 1):
     reuse the same mesh: slab shards the grid over ``fft`` with the batch
     on ``data``, while the pencil decomposition spends ``data`` on the
     second transform axis, scaling ONE grid over all ``data * shards``
-    devices. Defaults to all visible devices on ``fft``.
+    devices.
 
-    Requests that exceed the host shrink gracefully: ``data`` is clamped
-    first (dropping batch parallelism costs throughput, not correctness of
-    the pencil split), then ``shards`` rounds down to a power of two so the
-    default works on 3/5/6-device hosts (spare devices stay idle).
+    ``shards=None`` takes the largest power of two of the visible devices
+    per ``data`` row (spare devices stay idle). An explicit request is
+    built exactly as asked or refused: a mesh never silently holds fewer
+    devices than the caller named.
     """
     if data < 1:
         raise ValueError(f"data axis size must be >= 1, got {data}")
     n = len(jax.devices())
     if shards is None:
-        shards = max(1, n // data)
-    while data > 1 and data * shards > n:
-        data //= 2
+        shards = 1 << (max(1, n // data).bit_length() - 1)
+    if shards < 1 or shards & (shards - 1):
+        raise ValueError(f"the pencil split needs a power-of-two shard "
+                         f"count, got shards={shards}")
     if data * shards > n:
-        data, shards = 1, n
-    # the pencil split needs a power-of-two shard count
-    shards = 1 << (shards.bit_length() - 1)
+        raise ValueError(f"a data={data} x fft={shards} mesh needs "
+                         f"{data * shards} devices; {n} are visible")
     if data > 1:
-        return jax.make_mesh((data, shards), ("data", "fft"))
-    return jax.make_mesh((shards,), ("fft",))
+        return make_mesh((data, shards), ("data", "fft"))
+    return make_mesh((shards,), ("fft",))

@@ -364,6 +364,8 @@ def main():
                     help="LM-mode ABFT detection threshold (relative "
                          "per-column checksum divergence)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.mode == "fft":
         _main_fft(args)

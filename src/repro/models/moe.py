@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core import abft
 
@@ -184,7 +184,7 @@ def moe_block_ep(params, x, cfg, mesh, *, ft=None):
     if ft_on:
         out_specs = out_specs + (P(), P(), P())
     fn = shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
+                   out_specs=out_specs, check_vma=False)
     out = fn(x, params["router"], params["wi_gate"], params["wi_up"],
              params["wo"])
     y, aux = out[0], out[1]

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 __all__ = ["compress_allreduce_mean", "quantize_int8", "dequantize_int8"]
 
@@ -65,7 +65,7 @@ def compress_allreduce_mean(grads: Any, residual: Any, mesh: Mesh,
 
         spec = P(axes)
         fn = shard_map(reduce_fn, mesh=mesh, in_specs=(spec, spec),
-                       out_specs=(spec, spec), check_rep=False)
+                       out_specs=(spec, spec), check_vma=False)
         mean, new_r = fn(g, r)
         return mean.astype(g.dtype), new_r
 

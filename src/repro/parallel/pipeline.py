@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 __all__ = ["pipeline_apply"]
 
@@ -74,7 +74,7 @@ def pipeline_apply(
         per_stage, mesh=mesh,
         in_specs=(P(axis), P(None)),
         out_specs=P(axis),
-        check_rep=False)
+        check_vma=False)
     # output of every stage slot; the real result lives on the last stage —
     # slice it out (stage-major leading axis of size s)
     out_all = fn(stage_params, x[None])

@@ -91,11 +91,13 @@ def host_mesh():
     clamping to a 1-D single-device mesh when the request doesn't fit."""
     import jax
 
+    from repro.launch.mesh import make_mesh
+
     def make(*sizes, axes=("data", "model")):
         n = len(jax.devices())
         if int(np.prod(sizes)) > n:
             sizes, axes = (n,), (axes[0],)
-        return jax.make_mesh(sizes, axes)
+        return make_mesh(sizes, axes)
 
     return make
 
@@ -103,8 +105,11 @@ def host_mesh():
 def run_py(code: str, devices: int = 8, timeout: int = 600) -> str:
     """Run ``code`` in a subprocess with a forced multi-device host platform
     (the XLA device-count flag must be set before jax initializes, so it
-    cannot be applied inside the running test process)."""
+    cannot be applied inside the running test process). The child runs on
+    the CPU: an accelerator belongs to one process, and the parent may
+    hold it."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     out = subprocess.run([sys.executable, "-c", code], env=env,

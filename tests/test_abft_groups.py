@@ -53,8 +53,9 @@ def test_recompute_uncorrectable_rejects_jit():
     import jax.numpy as jnp
 
     from repro.core.fft.distributed import ft_distributed_fft
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((1,), ("fft",))
+    mesh = make_mesh((1,), ("fft",))
     x = jnp.ones((8, 256), jnp.complex64)
     with pytest.raises(ValueError, match="host-side fallback"):
         jax.jit(lambda v: ft_distributed_fft(
@@ -87,13 +88,12 @@ def test_collective_volume_grouped():
     assert g4["abft_overhead"] == pytest.approx(8 / b)
     assert g4["all_to_all_wire"] == pytest.approx(
         plain["all_to_all_wire"] * (b + 8) / b)
-    # psum payload at ring factor 2: grouped = (8G + 1) f32 scalars (the
-    # 5G stats-broadcast term is the masked all-reduce XLA emits for the
-    # replicated telemetry extraction); ungrouped = 4 f32 verdict scalars
-    # + native-scalar stats (3 pred + f32 score + s32 location = 11B) —
-    # per-kind HLO diffs in repro.analysis pinned down both layouts
+    # psum payload at ring factor 2: (8G + 1) f32 scalars for G = 4 and
+    # for the ungrouped G = 1 alike (the 5G stats-broadcast term is the
+    # masked all-reduce XLA emits for the replicated telemetry extraction)
+    # — per-kind HLO diffs in repro.analysis pin the layout down
     assert g4["psum_wire"] - g1["psum_wire"] == pytest.approx(
-        2.0 * (33 * 4 - (4 * 4 + 11)) * (d - 1) / d)
+        2.0 * (33 * 4 - 9 * 4) * (d - 1) / d)
     # data sharding divides rows, gather, and per-device verdict scalars
     half = collective_volume(n, b, d, ft=True, groups=4, data_shards=2)
     assert half["all_to_all_wire"] == pytest.approx(
@@ -112,12 +112,13 @@ def test_collective_volume_grouped():
 # the two lanes cannot drift apart. b=8 signals, G=4 groups of 2.
 _MATRIX_CODE = """
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core.fft.distributed import ft_distributed_fft
 
 dtype = np.{dtype}
 threshold = {threshold}
 tol = {tol}
-mesh = jax.make_mesh({mesh_shape}, {mesh_axes})
+mesh = make_mesh({mesh_shape}, {mesh_axes})
 rng = np.random.default_rng(3)
 b, n, g = 8, 1 << 12, 4
 x = (rng.standard_normal((b, n)) + 1j * rng.standard_normal((b, n))
@@ -226,10 +227,11 @@ def test_group_fault_matrix_subprocess(meshname):
 
 _HLO_CODE = """
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core.fft import distributed as dist
 from repro.launch.dryrun import collective_bytes
 
-mesh = jax.make_mesh((2, 2), ("data", "fft"))
+mesh = make_mesh((2, 2), ("data", "fft"))
 b, n, g = 8, 1 << 12, 4
 x = jnp.ones((b, n), jnp.complex64)
 for nat in (False, True):

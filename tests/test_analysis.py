@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.analysis import audit, hlo, lint
+from repro.launch.mesh import make_mesh
 
 # ---------------------------------------------------------------------------
 # hlo parser
@@ -171,7 +172,7 @@ def test_audit_catches_broken_volume_model(monkeypatch):
     monkeypatch.setattr(fft_api, "collective_volume", broken)
     plan_cache_clear()
     try:
-        mesh = jax.make_mesh((2,), ("fft",))
+        mesh = make_mesh((2,), ("fft",))
         spec = FFTSpec(shape=(8, 256), mesh=mesh)
         with pytest.raises(audit.AuditError) as ei:
             audit.audit_specs([spec], strict=True)

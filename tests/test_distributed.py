@@ -12,6 +12,7 @@ def test_param_specs_shard_and_run_training_step():
     finite, and the result matches the single-device run bit-for-bit."""
     out = run_py("""
 import dataclasses, numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_smoke_config
 from repro.configs.base import RunConfig, ParallelConfig
@@ -24,7 +25,7 @@ from repro.data import make_batch
 cfg = dataclasses.replace(get_smoke_config('phi3_medium_14b'),
                           vocab_size=128, num_layers=2, dtype='float32')
 model = Model(cfg)
-mesh = jax.make_mesh((4, 2), ('data', 'model'))
+mesh = make_mesh((4, 2), ('data', 'model'))
 params = model.init(jax.random.PRNGKey(0))
 specs = param_specs(jax.eval_shape(lambda: params), mesh)
 sharded = jax.tree_util.tree_map(
@@ -58,8 +59,9 @@ print('OK')
 def test_cache_specs_seq_sharding_for_long_decode():
     out = run_py("""
 import jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.parallel import cache_specs
-mesh = jax.make_mesh((4, 2), ('data', 'model'))
+mesh = make_mesh((4, 2), ('data', 'model'))
 cache = {'scan': {'slot0': {'k': jax.ShapeDtypeStruct((3, 1, 1024, 2, 64),
                                                       jnp.bfloat16),
                             'v': jax.ShapeDtypeStruct((3, 1, 1024, 2, 64),
@@ -75,9 +77,10 @@ print('OK', sp)
 def test_compressed_allreduce_close_to_exact():
     out = run_py("""
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.parallel import compress_allreduce_mean
-mesh = jax.make_mesh((8,), ('data',))
+mesh = make_mesh((8,), ('data',))
 rng = np.random.default_rng(0)
 g_global = rng.standard_normal((8, 64, 64)).astype(np.float32)
 # one different gradient shard per device: simulate with vmap-less loop
@@ -107,8 +110,9 @@ print('OK')
 def test_pipeline_stage_equivalence():
     out = run_py("""
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.parallel import pipeline_apply
-mesh = jax.make_mesh((4,), ('stage',))
+mesh = make_mesh((4,), ('stage',))
 rng = np.random.default_rng(0)
 ws = jnp.asarray(rng.standard_normal((4, 16, 16)).astype(np.float32) * 0.3)
 xs = jnp.asarray(rng.standard_normal((6, 8, 16)).astype(np.float32))
@@ -134,12 +138,13 @@ def test_dryrun_cell_on_host_mesh():
     config through the same code path as the production sweep."""
     out = run_py("""
 import dataclasses, jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_smoke_config
 from repro.configs.base import ParallelConfig, SHAPES, ShapeConfig
 import repro.launch.dryrun as dr
 
-mesh = jax.make_mesh((4, 2), ('data', 'model'))
+mesh = make_mesh((4, 2), ('data', 'model'))
 cfg = dataclasses.replace(get_smoke_config('gemma3_1b'), vocab_size=256)
 shape = ShapeConfig('t', 64, 8, 'train')
 par = ParallelConfig()

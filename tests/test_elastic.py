@@ -15,6 +15,7 @@ from conftest import run_py
 from repro import optim
 from repro.checkpoint import save_checkpoint
 from repro.launch.elastic import HeartbeatMonitor, elastic_restore
+from repro.launch.mesh import make_mesh
 
 
 def _toy_state(seed=0):
@@ -31,7 +32,7 @@ def test_elastic_restore_single_device(tmp_path):
     opt = type(opt)(step=jnp.int32(7), mu=opt.mu, nu=opt.nu)
     save_checkpoint(str(tmp_path), 7, (params, opt))
 
-    mesh = jax.make_mesh((len(jax.devices()), 1), ("data", "model"))
+    mesh = make_mesh((len(jax.devices()), 1), ("data", "model"))
     template = (jax.tree_util.tree_map(jnp.zeros_like, params), opt)
     (p_r, o_r), meta = elastic_restore(str(tmp_path), template, mesh)
 
@@ -59,6 +60,7 @@ def test_elastic_restore_smaller_mesh(tmp_path):
     smaller mesh (the fail-stop elasticity contract)."""
     out = run_py(f"""
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from jax.sharding import NamedSharding
 from repro import optim
 from repro.checkpoint import save_checkpoint
@@ -71,14 +73,14 @@ params = {{'layer': {{'w': jax.random.normal(k, (16, 8)),
 opt = optim.init_state(params)
 
 # the "before" job: 4x2 mesh, leaves sharded by its param specs
-big = jax.make_mesh((4, 2), ('data', 'model'))
+big = make_mesh((4, 2), ('data', 'model'))
 specs = param_specs(params, big)
 sharded = jax.tree_util.tree_map(
     lambda l, sp: jax.device_put(l, NamedSharding(big, sp)), params, specs)
 save_checkpoint({str(tmp_path)!r}, 3, (sharded, opt))
 
 # the "after" job: half the devices are gone
-small = jax.make_mesh((2, 2), ('data', 'model'))
+small = make_mesh((2, 2), ('data', 'model'))
 template = (jax.tree_util.tree_map(jnp.zeros_like, params), opt)
 (p_r, o_r), meta = elastic_restore({str(tmp_path)!r}, template, small)
 assert meta['step'] == 3, meta

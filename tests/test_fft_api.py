@@ -18,14 +18,15 @@ import jax.numpy as jnp
 
 from repro.core.fft import api
 from repro.core.fft.api import FFTSpec, FTConfig, plan
+from repro.launch.mesh import make_mesh
 
 
 def _mesh1():
-    return jax.make_mesh((4,), ("fft",))
+    return make_mesh((4,), ("fft",))
 
 
 def _mesh2():
-    return jax.make_mesh((2, 2), ("data", "fft"))
+    return make_mesh((2, 2), ("data", "fft"))
 
 
 def _need(n):
@@ -72,10 +73,10 @@ def test_spec_validation_messages():
 
 def test_spec_bad_axis_names():
     _need(2)
-    mesh = jax.make_mesh((2,), ("model",))
+    mesh = make_mesh((2,), ("model",))
     with pytest.raises(ValueError, match="'fft' .*model"):
         FFTSpec(shape=(8, 64), mesh=mesh)
-    mesh_f = jax.make_mesh((2,), ("fft",))
+    mesh_f = make_mesh((2,), ("fft",))
     with pytest.raises(ValueError, match="data_axis 'rows'"):
         FFTSpec(shape=(8, 64), mesh=mesh_f, data_axis="rows")
 

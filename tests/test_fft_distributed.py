@@ -38,6 +38,48 @@ def test_dist_plan_rejects_bad_sizes():
         make_dist_plan(8, 4)  # N < shards^2
 
 
+def test_fft_mesh_never_shrinks():
+    """An explicit shard request is built exactly or refused: a chip path
+    that asks for 4 devices must not silently run on fewer."""
+    import jax
+    from jax.sharding import AxisType
+
+    from repro.launch.mesh import make_fft_mesh
+
+    n = len(jax.devices())
+    with pytest.raises(ValueError, match="devices"):
+        make_fft_mesh(2 * n if n > 1 else 2)
+    with pytest.raises(ValueError, match="power-of-two"):
+        make_fft_mesh(3)
+    mesh = make_fft_mesh()
+    assert mesh.shape["fft"] == 1 << (n.bit_length() - 1)
+    assert set(mesh.axis_types) == {AxisType.Auto}
+
+
+def test_twiddle_block_accuracy_at_2_26():
+    """The in-graph pencil twiddle stays within 5e-7 of the float64 table
+    at N = 2^26 on 4 shards (512 x 131072), including blocks where the
+    angle index passes float32's 2^24 exact range and the angle nears pi."""
+    import jax.numpy as jnp
+
+    from repro.core.fft.distributed import _twiddle_block, make_dist_plan
+
+    n = 1 << 26
+    p = make_dist_plan(n, 4)
+    blocks = [((0, 8), (0, 256)), ((p.n1 - 8, 8), (p.n2 - 256, 256)),
+              ((p.n1 // 2 - 4, 8), (p.n2 // 2 - 128, 256)),
+              ((300, 8), (77777, 256))]
+    for inverse in (False, True):
+        for rows, cols in blocks:
+            t = np.asarray(_twiddle_block(p.n1, p.n2, rows, cols,
+                                          jnp.complex64, inverse))
+            k1 = np.arange(rows[0], rows[0] + rows[1])[:, None]
+            j = np.arange(cols[0], cols[0] + cols[1])[None, :]
+            sign = 1.0 if inverse else -1.0
+            ref = np.exp(sign * 2j * np.pi * ((k1 * j) % n) / n)
+            assert np.abs(t - ref).max() <= 5e-7, (inverse, rows, cols)
+
+
 def test_collective_volume_model():
     """One all-to-all; ABFT adds 2/B volume + scalars; transposed order
     skips the natural-order gather entirely."""
@@ -78,10 +120,11 @@ def test_distributed_fft_matches_numpy(shards):
     sharded ifft roundtrip and the transposed-order digit permutation."""
     out = run_py(f"""
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core.fft.distributed import (distributed_fft, distributed_ifft,
                                         make_dist_plan)
 shards = {shards}
-mesh = jax.make_mesh((shards,), ("fft",)) if shards > 1 else None
+mesh = make_mesh((shards,), ("fft",)) if shards > 1 else None
 rng = np.random.default_rng(shards)
 for ln in (14, 15, 16, 17):
     n = 1 << ln
@@ -147,8 +190,9 @@ def test_sharded_abft_detects_and_corrects_nonlocal_fault():
     checksums work across the mesh. Clean runs never false-alarm."""
     out = run_py("""
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core.fft.distributed import ft_distributed_fft
-mesh = jax.make_mesh((4,), ("fft",))
+mesh = make_mesh((4,), ("fft",))
 rng = np.random.default_rng(7)
 b, n = 8, 1 << 14
 x = (rng.standard_normal((b, n)) + 1j * rng.standard_normal((b, n))
@@ -187,8 +231,9 @@ def test_sharded_abft_fp64_telemetry():
     SEU — invisible at float32 — is detected, located, and corrected."""
     out = run_py("""
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core.fft.distributed import ft_distributed_fft
-mesh = jax.make_mesh((4,), ("fft",))
+mesh = make_mesh((4,), ("fft",))
 rng = np.random.default_rng(11)
 b, n = 8, 1 << 14
 x = (rng.standard_normal((b, n)) + 1j * rng.standard_normal((b, n))

@@ -11,6 +11,7 @@ import pytest
 import jax.numpy as jnp
 
 from conftest import run_py
+from repro.launch.mesh import make_mesh
 
 # ---------------------------------------------------------------------------
 # in-process: chooser + communication model
@@ -21,12 +22,12 @@ def test_choose_decomp_model_driven():
     import jax
     from repro.core.fft.multidim import choose_decomp
 
-    mesh1 = jax.make_mesh((1,), ("fft",))
+    mesh1 = make_mesh((1,), ("fft",))
     assert choose_decomp((64, 128), None) == "local"
     assert choose_decomp((64, 128), mesh1) == "local"
     if len(jax.devices()) < 2:
         return
-    mesh = jax.make_mesh((2,), ("fft",))
+    mesh = make_mesh((2,), ("fft",))
     # slab feasible: wins on volume (one all-to-all) / ties
     assert choose_decomp((64, 128), mesh, batch=8) == "slab"
     # slab infeasible (first axis does not divide): pencil takes over
@@ -44,7 +45,7 @@ def test_choose_decomp_2d_mesh_tiebreak():
 
     if len(jax.devices()) < 4:
         pytest.skip("needs >= 4 host devices")
-    mesh2 = jax.make_mesh((2, 2), ("data", "fft"))
+    mesh2 = make_mesh((2, 2), ("data", "fft"))
     assert choose_decomp((64, 128), mesh2, batch=1) == "slab"
     assert choose_decomp((64, 128), mesh2, batch=1,
                          natural_order=False) == "pencil"
@@ -57,7 +58,7 @@ def test_choose_decomp_infeasible_raises():
 
     if len(jax.devices()) < 2:
         pytest.skip("needs >= 2 host devices")
-    mesh = jax.make_mesh((2,), ("fft",))
+    mesh = make_mesh((2,), ("fft",))
     with pytest.raises(ValueError, match="no feasible decomposition"):
         choose_decomp((3, 5), mesh)   # not powers of two
 
@@ -200,11 +201,12 @@ def test_fft_convolve2_local_matches_reference(rng):
 
 _EQUIV_CODE = """
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core.fft import multidim as md
 from repro.parallel.fft_sharding import shard_grid
 
-mesh1 = jax.make_mesh((4,), ("fft",))
-mesh2 = jax.make_mesh((2, 2), ("data", "fft"))
+mesh1 = make_mesh((4,), ("fft",))
+mesh2 = make_mesh((2, 2), ("data", "fft"))
 rng = np.random.default_rng(5)
 
 def rel(a, b):
@@ -268,12 +270,13 @@ print('OK')
 
 _FT_CODE = """
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core.fft import multidim as md
 
 dtype = np.{dtype}
 threshold = {threshold}
 tol = {tol}
-mesh = jax.make_mesh({mesh_shape}, {mesh_axes})
+mesh = make_mesh({mesh_shape}, {mesh_axes})
 rng = np.random.default_rng(9)
 b, rr, cc, g = 8, 32, 64, 4
 x = (rng.standard_normal((b, rr, cc)) +
@@ -334,10 +337,11 @@ print('OK')
 # and the slab forward is exactly one all-to-all with zero gathers
 _HLO_CODE = """
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core.fft import multidim as md
 from repro.launch.dryrun import collective_bytes
 
-mesh2 = jax.make_mesh((2, 2), ("data", "fft"))
+mesh2 = make_mesh((2, 2), ("data", "fft"))
 b, rr, cc, g = 8, 128, 256, 4
 x = jnp.ones((b, rr, cc), jnp.complex64)
 fn = md._ft_slab_fft2_fn(mesh2, "fft", 1e-4, True, g, "data")

@@ -36,11 +36,12 @@ from repro.core.fft import spectral as spec
 from repro.core.fft.api import FFTSpec, FTConfig, plan
 from repro.core.fft.distributed import (CHUNK_LATENCY_BYTES, choose_chunks,
                                         resolve_chunks)
+from repro.launch.mesh import make_mesh
 from repro.parallel.fft_sharding import chunk_layout
 
 
 def _mesh1():
-    return jax.make_mesh((4,), ("fft",))
+    return make_mesh((4,), ("fft",))
 
 
 def _need(n):
@@ -97,7 +98,7 @@ def test_chunk_layout_no_mesh():
 
 def test_chunk_layout_on_2d_mesh():
     _need(4)
-    mesh = jax.make_mesh((2, 2), ("data", "fft"))
+    mesh = make_mesh((2, 2), ("data", "fft"))
     # 8 rows over 2 data shards: 4 resident rows -> up to 4 transactions
     assert chunk_layout(mesh, 8, 8) == (4, 1)
     assert chunk_layout(mesh, 8, 2, groups=4) == (2, 2)

@@ -109,6 +109,29 @@ def test_extensions_rfft2_rejects_complex(crand):
         rfft2(crand(2, 64).reshape(2, 8, 8))
 
 
+@pytest.mark.parametrize("shape,rank", [((4, 64), 1), ((2, 8, 16), 2)])
+def test_real_float32_plan_has_no_complex128(shape, rank, caplog):
+    """A float32 real plan stays complex64 end to end, even with x64 on:
+    the TPU compiler aborts on complex128. Traced, the Hermitian-unpack
+    twiddles were an on-device exp of int64 iotas; called eagerly (as the
+    serving runtime calls a rank-1 plan), the packing trick's Python
+    complex scalars reached the device as complex128 operands."""
+    import logging
+
+    import jax
+
+    from repro.core.fft.api import FFTSpec, plan
+
+    p = plan(FFTSpec(shape=shape, rank=rank, real=True))
+    half = shape[:-1] + (shape[-1] // 2 + 1,)
+    for fn, arg in ((p.rfft, jnp.zeros(shape, jnp.float32)),
+                    (p.irfft, jnp.zeros(half, jnp.complex64))):
+        assert "complex<f64>" not in jax.jit(fn).lower(arg).as_text()
+        with jax.log_compiles(True), caplog.at_level(logging.WARNING):
+            fn(arg)     # eager: each program compiled is logged with its types
+    assert "complex128" not in caplog.text
+
+
 def test_power_spectrum_real_one_sided(rng):
     from repro.core.fft.spectral import power_spectrum
 
@@ -264,12 +287,13 @@ def test_serve_fft_real_rejects_complex(crand):
 
 _REAL_EQUIV_CODE = """
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core.fft import multidim as md
 from repro.core.fft.api import FFTSpec, plan, spec_for
 from repro.parallel.fft_sharding import shard_grid
 
-mesh1 = jax.make_mesh((4,), ("fft",))
-mesh2 = jax.make_mesh((2, 2), ("data", "fft"))
+mesh1 = make_mesh((4,), ("fft",))
+mesh2 = make_mesh((2, 2), ("data", "fft"))
 rng = np.random.default_rng(11)
 
 def rel(a, b):
@@ -344,13 +368,14 @@ print('OK')
 
 _REAL_FT_CODE = """
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core.fft import multidim as md
 from repro.core.fft.api import FFTSpec, FTConfig, plan
 
 dtype = np.{dtype}
 threshold = {threshold}
 tol = {tol}
-mesh = jax.make_mesh({mesh_shape}, {mesh_axes})
+mesh = make_mesh({mesh_shape}, {mesh_axes})
 rng = np.random.default_rng(13)
 b, rr, cc, g = 8, 32, 64, 4
 shards = mesh.shape["fft"]

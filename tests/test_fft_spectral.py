@@ -86,9 +86,8 @@ def test_collective_volume_psum_tracks_itemsize():
     """The grouped ABFT verdict traffic is 8 scalars per checksum group
     (3 verdict-psum + 5 replicated-stats broadcast) plus one shared energy
     scalar, in the input's REAL dtype: f64 for complex128 — the model must
-    scale with both the group count and the itemsize. The UNGROUPED
-    pipeline reduces its native stats scalars instead: 3 predicate flags
-    (1B), the score in the real dtype, and an s32 location."""
+    scale with both the group count and the itemsize. The ungrouped
+    pipeline (G = 1) has the same layout."""
     from repro.core.fft.distributed import collective_volume
 
     n, b, d = 1 << 14, 8, 4
@@ -101,9 +100,9 @@ def test_collective_volume_psum_tracks_itemsize():
                                   itemsize=itemsize)
         return ft["hlo_bytes"] - plain["hlo_bytes"]
 
-    assert psum_bytes(8) == pytest.approx(2.0 * (4 * 4 + 3 + 4 + 4))
+    assert psum_bytes(8) == pytest.approx(2.0 * (4 * 4 + 5 * 4))
     # pre-fix the verdict+score were f32-sized under complex128:
-    assert psum_bytes(16) == pytest.approx(2.0 * (4 * 8 + 3 + 8 + 4))
+    assert psum_bytes(16) == pytest.approx(2.0 * (4 * 8 + 5 * 8))
     assert psum_bytes(8, groups=4) == pytest.approx(2.0 * 33 * 4)
     # grouped + data-sharded: each device psums only its own groups' stats
     half = collective_volume(n, b, d, ft=True, natural_order=False,
@@ -128,6 +127,7 @@ def test_transposed_order_invariants_and_convolve_on_mesh():
     kernels.ops entry points thread natural_order through."""
     out = run_py("""
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core.fft.distributed import (distributed_fft, distributed_ifft,
                                         spectral_volume)
 from repro.core.fft import spectral
@@ -140,7 +140,7 @@ x = (rng.standard_normal((b, n)) + 1j * rng.standard_normal((b, n))
      ).astype(np.complex64)
 
 # ---- 1-D fft mesh: transposed round trip -------------------------------
-mesh = jax.make_mesh((4,), ("fft",))
+mesh = make_mesh((4,), ("fft",))
 yt = distributed_fft(x, mesh, natural_order=False)
 back = np.asarray(distributed_ifft(yt, mesh, natural_order=False))
 assert np.abs(back - x).max() / np.abs(x).max() < 4e-5

@@ -291,6 +291,7 @@ def test_moe_ep_ft_stats_escape_shard_map():
     the protected portable forward."""
     out = run_py("""
 import dataclasses, numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_smoke_config
 from repro.core.ft import FTPolicy
@@ -309,7 +310,7 @@ ctx_ref = FTContext(pol)
 y_ref, _ = _moe_block_portable(params, x, cfg, ft=ctx_ref)
 ref_sum = ctx_ref.summary()
 
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = make_mesh((2, 4), ('data', 'model'))
 ps = dict(params)
 for k in ('wi_gate', 'wi_up', 'wo'):
     ps[k] = jax.device_put(params[k], NamedSharding(mesh,

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from repro.core.ft import (FaultSchedule, FTPolicy, flip_bit,
                            poisson_schedule, random_flip)
+from repro.launch.mesh import make_mesh
 
 
 # ---------------------------------------------------------------------------
@@ -173,11 +174,9 @@ def test_mesh_threshold_edge_matches_policy(crand):
     run scores far under any sane threshold, and setting the threshold to
     the exact observed score of an injected fault un-flags it while any
     smaller threshold flags it — i.e. the knob is a true ROC dial."""
-    import jax
-
     from repro.core.fft.distributed import ft_distributed_fft
 
-    mesh = jax.make_mesh((1,), ("fft",))
+    mesh = make_mesh((1,), ("fft",))
     x = crand(8, 256)
     inj = jnp.asarray([[0, 5, 3, 7, 1, 60.0, -25.0]], jnp.float32)
     res = ft_distributed_fft(x, mesh, groups=4, inject=inj)

@@ -63,6 +63,14 @@ def test_ops_ifft_roundtrip(crand):
     np.testing.assert_allclose(got, x, atol=2e-6 * np.abs(x).max())
 
 
+def test_ops_ifft_roundtrip_multipass(crand):
+    """Each pass's block kernel applies its own 1/f_i: the multi-pass
+    inverse is normalized once, by 1/N."""
+    x = crand(4, 1 << 14)
+    got = np.asarray(ops.ifft(ops.fft(x)))
+    np.testing.assert_allclose(got, x, atol=2e-6 * np.abs(x).max())
+
+
 # ---------------------------------------------------------------------------
 # Fused two-sided ABFT kernel
 # ---------------------------------------------------------------------------

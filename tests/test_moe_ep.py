@@ -9,6 +9,7 @@ pytestmark = pytest.mark.slow  # every test compiles on an 8-way subprocess
 def test_ep_matches_portable():
     out = run_py("""
 import dataclasses, numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_smoke_config
 from repro.models.moe import make_moe_params, moe_block, _moe_block_portable
@@ -21,7 +22,7 @@ x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model),
                       jnp.float32)
 y_ref, aux_ref = _moe_block_portable(params, x, cfg)
 
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = make_mesh((2, 4), ('data', 'model'))
 xs = jax.device_put(x, NamedSharding(mesh, P('data', None, None)))
 ps = {k: jax.device_put(v, NamedSharding(mesh, P())) if k == 'router' or
       isinstance(v, dict) else v for k, v in params.items()}
@@ -44,6 +45,7 @@ def test_ep_collectives_are_one_psum_per_layer():
     all-reduces (the §Perf Cell-1 property)."""
     out = run_py(r"""
 import dataclasses, re, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_smoke_config
 from repro.models.moe import make_moe_params, moe_block
@@ -52,7 +54,7 @@ cfg = dataclasses.replace(get_smoke_config('deepseek_v3_671b'),
                           num_experts=8, top_k=2, dtype='float32')
 params = jax.eval_shape(lambda: make_moe_params(jax.random.PRNGKey(0), cfg,
                                                 jnp.float32))
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = make_mesh((2, 4), ('data', 'model'))
 def sds(l, sp):
     return jax.ShapeDtypeStruct(l.shape, l.dtype,
                                 sharding=NamedSharding(mesh, sp))

@@ -14,6 +14,7 @@ import jax.numpy as jnp  # noqa: E402
 
 from repro.core import abft  # noqa: E402
 from repro.kernels import ops  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 
 # hypothesis: ft_matmul detects any sufficiently large injected error
@@ -39,11 +40,9 @@ def test_property_ft_matmul_detects(row, col, eps):
 @given(g=st.sampled_from([1, 2, 4, 8]), ln=st.integers(8, 10),
        seed=st.integers(0, 2 ** 16))
 def test_property_group_count_invariance(g, ln, seed):
-    import jax
-
     from repro.core.fft.distributed import ft_distributed_fft
 
-    mesh = jax.make_mesh((1,), ("fft",))
+    mesh = make_mesh((1,), ("fft",))
     rng = np.random.default_rng(seed)
     b, n = 8, 1 << ln
     x = (rng.standard_normal((b, n)) + 1j * rng.standard_normal((b, n))
@@ -63,12 +62,10 @@ def test_property_group_count_invariance(g, ln, seed):
 @given(c=st.integers(1, 8), ln=st.integers(8, 10),
        natural=st.booleans(), seed=st.integers(0, 2 ** 16))
 def test_property_chunk_count_invariance(c, ln, natural, seed):
-    import jax
-
     from repro.core.fft.distributed import (distributed_fft,
                                             ft_distributed_fft)
 
-    mesh = jax.make_mesh((1,), ("fft",))
+    mesh = make_mesh((1,), ("fft",))
     rng = np.random.default_rng(seed)
     b, n = 8, 1 << ln
     x = (rng.standard_normal((b, n)) + 1j * rng.standard_normal((b, n))
@@ -97,11 +94,9 @@ def test_property_chunk_count_invariance(c, ln, natural, seed):
 )
 def test_property_injection_roundtrip(g, sig, row, col, eps_r, eps_i):
     assume(abs(eps_r) + abs(eps_i) > 5.0)  # above noise floor
-    import jax
-
     from repro.core.fft.distributed import ft_distributed_fft
 
-    mesh = jax.make_mesh((1,), ("fft",))
+    mesh = make_mesh((1,), ("fft",))
     rng = np.random.default_rng(sig * 256 + row * 16 + col)
     b, n = 8, 256
     x = (rng.standard_normal((b, n)) + 1j * rng.standard_normal((b, n))

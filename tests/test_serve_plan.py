@@ -69,10 +69,11 @@ FT_KEYS = ("ft", "groups", "group_size", "score", "flagged", "locations",
 def test_ft_telemetry_complete_grouped_and_real_mesh():
     out = run_py(f"""
 import numpy as np, jax
+from repro.launch.mesh import make_mesh
 from repro.core.fft import api
 from repro.launch.serve import build_fft_spec, serve_plan
 
-mesh = jax.make_mesh((4,), ('fft',))
+mesh = make_mesh((4,), ('fft',))
 rng = np.random.default_rng(0)
 KEYS = {FT_KEYS!r}
 

@@ -1,0 +1,98 @@
+"""Compile the Pallas kernels for a described TPU v5e, without running them.
+
+The TPU compiler is installed even where no chip is attached: it compiles
+for a topology that is only described. That catches what interpret mode
+cannot — Mosaic layout refusals, 64-bit index types, VMEM over the scoped
+limit — at the sizes the chip path runs. Each test asserts that the
+compiled program holds a Mosaic kernel (``tpu_custom_call``), i.e. that
+nothing fell back to interpret mode.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process at a time may load the TPU library, and every test worker
+imports this file.
+"""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.fft.plan import make_plan
+from repro.kernels import ops
+from repro.kernels.ft_matmul import _ft_matmul_pallas
+from repro.kernels.stockham import block_fft_pallas
+from repro.kernels.stockham_abft import abft_fft_pallas
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # a compile for a described chip can be written to the persistent
+    # cache but never read back without one: keep the cache out of it
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("n,batch", [(1 << 10, 1 << 16), (1 << 13, 1 << 13)])
+def test_block_fft_compiles(n, batch, one_chip):
+    x = _sds((batch, n), jnp.float32, one_chip)
+    bs = make_plan(n, batch=batch).bs
+    compiled = block_fft_pallas.lower(x, x, bs=bs,
+                                      interpret=False).compile()
+    _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("n,batch,factors", [
+    (1 << 17, 1 << 9, (512, 256)),
+    (1 << 23, 8, (256, 256, 128)),
+])
+def test_multipass_fft_compiles(n, batch, factors, one_chip):
+    assert make_plan(n, batch=batch).kernel_factors == factors
+    x = _sds((batch, n), jnp.complex64, one_chip)
+    compiled = ops._fft_impl.lower(x, inverse=False,
+                                   interpret=False).compile()
+    _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("per_signal", [False, True])
+def test_abft_fft_compiles(per_signal, one_chip):
+    n, batch = 1 << 10, 1 << 16
+    x = _sds((batch, n), jnp.float32, one_chip)
+    inj = _sds((6,), jnp.float32, one_chip)
+    compiled = abft_fft_pallas.lower(
+        x, x, transactions=4, per_signal=per_signal, interpret=False,
+        inject=inj).compile()
+    _assert_kernel(compiled)
+
+
+def test_ft_matmul_compiles(one_chip):
+    m, k, n = 512, 4096, 512
+    x = _sds((m, k), jnp.float32, one_chip)
+    w = _sds((k, n), jnp.float32, one_chip)
+    inj = _sds((1, 4), jnp.float32, one_chip)
+    compiled = _ft_matmul_pallas.lower(x, w, inj, bm=128, bn=128, bk=128,
+                                       interpret=False).compile()
+    _assert_kernel(compiled)
+    assert np.dtype(jnp.float32) == compiled.out_info.c.dtype
