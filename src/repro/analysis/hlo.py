@@ -18,7 +18,8 @@ from __future__ import annotations
 import dataclasses
 import re
 
-__all__ = ["CollectiveOp", "COLLECTIVE_KINDS", "DTYPE_BYTES", "WIRE_FACTOR",
+__all__ = ["CollectiveOp", "COLLECTIVE_KINDS", "COMPLEX_CONVERSIONS",
+           "DTYPE_BYTES", "PROGRAM_SCOPES", "WIRE_FACTOR", "op_scopes",
            "parse_collectives", "summarize", "root_signature"]
 
 COLLECTIVE_KINDS = ("all-reduce", "all-gather", "reduce-scatter",
@@ -35,6 +36,33 @@ DTYPE_BYTES = {"f64": 8, "f32": 4, "bf16": 2, "f16": 2, "s32": 4, "u32": 4,
 # wire-bytes multiplier per collective kind (ring algorithms)
 WIRE_FACTOR = {"all-reduce": 2.0, "all-gather": 1.0, "reduce-scatter": 1.0,
                "all-to-all": 1.0, "collective-permute": 1.0}
+
+# the prefixes of the program's ``jax.named_scope`` names (kernels/ops.py,
+# kernels/stockham*.py, core/abft/twoside.py)
+PROGRAM_SCOPES = ("fft.", "abft.")
+# The TPU compiler carries a 64-bit value as two 32-bit planes and lowers
+# real/imag of a complex value to the custom calls X64SplitLow/High and
+# complex() to X64Combine. Their metadata is not always their own: a split
+# carries that of the value's producer (for an entry parameter, its name),
+# and a combine carries the complex op's only where that op was the result,
+# else none. So a conversion between a complex value and its planes whose
+# metadata has no program scope, or (a split) names its producer's, takes
+# the scope of the conversion it implements; one with a scope of its own
+# keeps it. A split of a value made inside another scope therefore counts
+# under fft.split. In the v5e compile of the 2^10 C2C and fused-ABFT
+# executors the only such conversions are the splits of the entry parameter
+# (op_name "x") and the final combine (no metadata).
+COMPLEX_CONVERSIONS = {"X64SplitLow": "fft.split",
+                       "X64SplitHigh": "fft.split",
+                       "X64Combine": "fft.join"}
+
+# [ROOT] %name = <result type> opcode(%operand, ...), attr=..., ...
+INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w\-\.]+)\s*=\s*(.*?)\s"
+                      r"([a-z][\w\-]*)\((.*)$")
+OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+TARGET_RE = re.compile(r'custom_call_target="([^"]+)"')
+OPERAND_RE = re.compile(r"%([\w\-\.]+)")
+COMPLEX_RE = re.compile(r"^\(?c(?:64|128)\[")
 
 # ENTRY %main.123 (arg0: c64[8,256]) -> (c64[8,256], f32[3]) {
 ENTRY_RE = re.compile(r"^ENTRY\s+\S+\s*\(.*\)\s*->\s*(.*?)\s*\{?\s*$")
@@ -135,3 +163,47 @@ def root_signature(hlo_text: str) -> tuple[str, ...]:
         if m:
             return tuple(mm.group(1) for mm in SHAPE_RE.finditer(m.group(1)))
     return ()
+
+
+def op_scopes(text: str, prefixes=PROGRAM_SCOPES) -> dict[str, str]:
+    """``{instruction name: program scope}`` for a compiled module's text
+    (``jax.stages.Compiled.as_text()``).
+
+    An instruction's scope is the innermost element of its metadata's
+    ``op_name`` path (``jit(f)/jit(g)/fft.layout/reshape``) that starts with
+    one of ``prefixes``. A fusion carries its root's metadata, so its whole
+    time counts under the root's scope; so does a copy that layout
+    assignment put in for an op's result. A complex <-> planes conversion
+    whose metadata is not its own takes the scope of what it implements
+    (see :data:`COMPLEX_CONVERSIONS`). Instructions with no scope
+    (parameters, compiler-inserted transfers with no metadata) are left
+    out. The names are those a profile's device ops carry, so the map reads
+    an XProf trace of this executable by program stage.
+    """
+    rows = {}     # name -> (result type, rest of line, own scope, operand)
+    for line in text.splitlines():
+        m = INSTR_RE.match(line)
+        if not m:
+            continue
+        name, rtype, _, rest = m.groups()
+        meta = OP_NAME_RE.search(rest)
+        scoped = [e for e in meta.group(1).split("/")
+                  if e.startswith(prefixes)] if meta else []
+        operand = OPERAND_RE.search(rest)
+        rows[name] = (rtype, rest, scoped[-1] if scoped else None,
+                      operand.group(1) if operand else None)
+    out = {}
+    for name, (rtype, rest, scope, operand) in rows.items():
+        t = TARGET_RE.search(rest)
+        conversion = COMPLEX_CONVERSIONS.get(t.group(1)) if t else None
+        if conversion == "fft.join":
+            if scope is None and COMPLEX_RE.match(rtype):
+                scope = conversion
+        elif conversion == "fft.split":
+            src = rows.get(operand)
+            if src is not None and COMPLEX_RE.match(src[0]) \
+                    and scope in (None, src[2]):
+                scope = conversion
+        if scope is not None:
+            out[name] = scope
+    return out

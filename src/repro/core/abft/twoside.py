@@ -39,6 +39,7 @@ class GroupChecksums:
     cs3_out: jax.Array
 
     @classmethod
+    @jax.named_scope("abft.decode")
     def from_packed(cls, cs: jax.Array) -> "GroupChecksums":
         """From the kernel's packed (G, 8, N) float layout."""
         c = lambda j: cs[:, 2 * j] + 1j * cs[:, 2 * j + 1]
@@ -55,6 +56,7 @@ class Verdict:
     correction: jax.Array    # (G, N) complex correction value (-eps)
 
 
+@jax.named_scope("abft.decode")
 def detect_locate(
     cs: GroupChecksums,
     forward: Callable[[jax.Array], jax.Array],
@@ -79,6 +81,7 @@ def detect_locate(
                    correction=d2)
 
 
+@jax.named_scope("abft.correct")
 def apply_correction(y: jax.Array, verdict: Verdict) -> tuple[jax.Array, jax.Array]:
     """Add the correction value back onto the located signals (paper §4.1.2).
 

@@ -787,12 +787,8 @@ class FFTPlan(planbase.Plan):
                 recompute_uncorrectable=ft.recompute_uncorrectable)
         if self.rank == 1 and not self.sharded:
             from repro.kernels import ops as _ops
-            res = _ops._ft_fft_local(
-                x, transactions=ft.transactions, bs=bs,
-                per_signal=ft.per_signal, encoding=ft.encoding,
-                threshold=ft.threshold, correct=ft.correct,
-                interpret=self.spec.interpret, inject=inject)
-            return res
+            return _ops._ft_fft_local(x, inject=inject,
+                                      **self._ft_local_kw(bs))
         if self.rank == 1:
             return ft_distributed_fft(
                 x, self.mesh, axis=self.spec.axis, threshold=ft.threshold,
@@ -805,6 +801,43 @@ class FFTPlan(planbase.Plan):
             correct=ft.correct, inject=inject, groups=self.groups,
             data_axis=self.daxis,
             recompute_uncorrectable=ft.recompute_uncorrectable)
+
+    def _ft_local_kw(self, bs):
+        """The static arguments of the local fused-kernel ABFT executor."""
+        ft = self.spec.ft
+        return dict(transactions=ft.transactions, bs=bs,
+                    per_signal=ft.per_signal, encoding=ft.encoding,
+                    threshold=ft.threshold, correct=ft.correct,
+                    interpret=self.spec.interpret)
+
+    def executor_hlo(self, x, *, op: str = "fft", inject=None) -> str:
+        """The compiled module text of the executor ``getattr(self, op)(x,
+        ...)`` runs on a local rank-1 C2C plan: ``_fft_impl`` for ``"fft"``,
+        ``_ft_fft_local`` for ``"ft_fft"`` (``inject`` as the caller passes
+        it; an array or a ``jax.ShapeDtypeStruct``).
+
+        It lowers the jitted function the plan calls with the static
+        arguments the plan binds, so on a warm compile cache it loads the
+        executable the calls ran and its instruction names are those of
+        their profile's device ops.
+        ``repro.analysis.hlo.op_scopes(plan.executor_hlo(x))`` maps each op
+        of an XProf trace to its program stage (``fft.split``,
+        ``fft.layout``, ``fft.kernel``, ``fft.join``, ``abft.decode``, ...).
+        """
+        if self.rank != 1 or self.sharded or self.spec.real:
+            raise ValueError("executor_hlo covers the local rank-1 C2C "
+                             f"executors; this is {self!r}")
+        if op == "fft":
+            lowered = self._fwd.func.lower(x, **self._fwd.keywords)
+        elif op == "ft_fft":
+            if self.spec.ft is None:
+                raise ValueError("this plan has no FTConfig — set FFTSpec.ft")
+            from repro.kernels import ops as _ops
+            lowered = _ops._ft_fft_local.lower(x, inject=inject,
+                                               **self._ft_local_kw(None))
+        else:
+            raise ValueError(f"op must be 'fft' or 'ft_fft', got {op!r}")
+        return lowered.compile().as_text()
 
     # -- spectral consumers ----------------------------------------------
 

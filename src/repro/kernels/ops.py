@@ -59,11 +59,13 @@ def _resolve_legacy(entry: str, kw: dict) -> dict:
     return out
 
 
+@jax.named_scope("fft.split")
 def _split(x):
     ftype = jnp.float64 if x.dtype == jnp.complex128 else jnp.float32
     return jnp.real(x).astype(ftype), jnp.imag(x).astype(ftype)
 
 
+@jax.named_scope("fft.join")
 def _join(yr, yi):
     return jax.lax.complex(yr, yi)
 
@@ -72,7 +74,9 @@ def _pad_batch(x, bs):
     b = x.shape[0]
     pad = (-b) % bs
     if pad:
-        x = jnp.concatenate([x, jnp.zeros((pad,) + x.shape[1:], x.dtype)])
+        with jax.named_scope("fft.layout"):
+            x = jnp.concatenate([x, jnp.zeros((pad,) + x.shape[1:],
+                                              x.dtype)])
     return x, b
 
 
@@ -87,7 +91,9 @@ def _block_fft_c(x2d, *, inverse, interpret, bs=None):
     xi, _ = _pad_batch(xi, bs)
     yr, yi = block_fft_pallas(xr, xi, plan=dataclasses.replace(plan),
                               bs=bs, inverse=inverse, interpret=interpret)
-    return _join(yr, yi)[:b0]
+    y = _join(yr, yi)
+    with jax.named_scope("fft.layout"):
+        return y[:b0]
 
 
 def _fft_multipass(x2d, plan: Plan, *, inverse, interpret):
@@ -106,16 +112,21 @@ def _fft_multipass(x2d, plan: Plan, *, inverse, interpret):
                                 interpret=interpret).reshape(z.shape)
         f1 = facs[0]
         f2 = int(np.prod(facs[1:]))
-        zz = z.reshape(z.shape[:-1] + (f1, f2))
-        zz = jnp.swapaxes(zz, -1, -2)  # (..., f2, f1)
+        with jax.named_scope("fft.layout"):
+            zz = z.reshape(z.shape[:-1] + (f1, f2))
+            zz = jnp.swapaxes(zz, -1, -2)  # (..., f2, f1)
         zz = rec(zz, (f1,))
-        zz = jnp.swapaxes(zz, -1, -2)  # (..., f1, f2)
-        tw = jnp.asarray(fft_factors.stage_twiddle(f1, f2, inverse=inverse),
-                         dtype=z.dtype)
-        zz = zz * tw
+        with jax.named_scope("fft.layout"):
+            zz = jnp.swapaxes(zz, -1, -2)  # (..., f1, f2)
+        with jax.named_scope("fft.twiddle"):
+            tw = jnp.asarray(fft_factors.stage_twiddle(f1, f2,
+                                                       inverse=inverse),
+                             dtype=z.dtype)
+            zz = zz * tw
         zz = rec(zz, facs[1:])
-        zz = jnp.swapaxes(zz, -1, -2)
-        return zz.reshape(z.shape)
+        with jax.named_scope("fft.layout"):
+            zz = jnp.swapaxes(zz, -1, -2)
+            return zz.reshape(z.shape)
 
     return rec(x2d, facs)
 
@@ -340,11 +351,15 @@ def _ft_fft_local(
         sums, forward=lambda c: block_fft_stages(c), threshold=threshold)
     if correct:
         y, _ = abft.apply_correction(y, verdict)
+    with jax.named_scope("fft.layout"):
+        y, delta = y[:b], delta[:b]
+    with jax.named_scope("abft.decode"):
+        corrected = jnp.sum(verdict.flagged.astype(jnp.int32))
     return FTFFTResult(
-        y=y[:b],
-        delta=delta[:b],
+        y=y,
+        delta=delta,
         group_score=verdict.error_score,
         flagged=verdict.flagged,
         location=verdict.location,
-        corrected=jnp.sum(verdict.flagged.astype(jnp.int32)),
+        corrected=corrected,
     )

@@ -63,6 +63,7 @@ def chunk_rows(rows: int) -> int:
     return math.gcd(rows, CHUNK_ROWS)
 
 
+@jax.named_scope("fft.layout")
 def to_blocks(x: jax.Array) -> jax.Array:
     """(B, n) -> (B * R, L), rows ``(signal, n1)``."""
     b, n = x.shape
@@ -70,6 +71,7 @@ def to_blocks(x: jax.Array) -> jax.Array:
     return x.reshape(b * r, lanes)
 
 
+@jax.named_scope("fft.layout")
 def from_blocks(y: jax.Array, n: int) -> jax.Array:
     """(B * R, L) with rows ``(signal, k1)`` -> (B, n) natural order."""
     r, lanes = block_layout(n)
@@ -189,15 +191,17 @@ def block_fft_pallas(
               layout_consts(n, ch, np_dtype, inverse=inverse)]
 
     x_spec = pl.BlockSpec((rows, lanes), lambda i: (i, jnp.int32(0)))
-    yr, yi = pl.pallas_call(
-        functools.partial(_fft_kernel, ch, len(consts)),
-        grid=(b // bs,),
-        in_specs=[x_spec, x_spec] + const_specs(consts),
-        out_specs=[x_spec, x_spec],
-        out_shape=[jax.ShapeDtypeStruct((b * r, lanes), xr.dtype)] * 2,
-        interpret=resolve_interpret(interpret),
-        name="block_fft",
-    )(to_blocks(xr), to_blocks(xi), *consts)
+    xr, xi = to_blocks(xr), to_blocks(xi)
+    with jax.named_scope("fft.kernel"):
+        yr, yi = pl.pallas_call(
+            functools.partial(_fft_kernel, ch, len(consts)),
+            grid=(b // bs,),
+            in_specs=[x_spec, x_spec] + const_specs(consts),
+            out_specs=[x_spec, x_spec],
+            out_shape=[jax.ShapeDtypeStruct((b * r, lanes), xr.dtype)] * 2,
+            interpret=resolve_interpret(interpret),
+            name="block_fft",
+        )(xr, xi, *consts)
     yr, yi = from_blocks(yr, n), from_blocks(yi, n)
     if inverse:
         scale = jnp.asarray(1.0 / n, dtype=xr.dtype)

@@ -180,11 +180,12 @@ def abft_fft_pallas(
 
     if inject is None:
         inject = jnp.full((6,), -1.0, dtype=jnp.float32)
-    fields = jnp.round(inject[:4]).astype(jnp.int32)
-    tile, row, col, enabled = fields[0], fields[1], fields[2], fields[3]
-    inj_i = jnp.stack([(tile * bs + row) * r + col % r, col // r,
-                       enabled])[None]
-    inj_f = inject[4:6].astype(np_dtype)[None]
+    with jax.named_scope("fft.kernel"):     # the kernel's SMEM operands
+        fields = jnp.round(inject[:4]).astype(jnp.int32)
+        tile, row, col, enabled = fields[0], fields[1], fields[2], fields[3]
+        inj_i = jnp.stack([(tile * bs + row) * r + col % r, col // r,
+                           enabled])[None]
+        inj_f = inject[4:6].astype(np_dtype)[None]
 
     x_spec = pl.BlockSpec(
         (rows, lanes), lambda g, t: (g * transactions + t, jnp.int32(0)))
@@ -200,31 +201,34 @@ def abft_fft_pallas(
 
     kernel = functools.partial(_abft_kernel, r, ch, transactions, per_signal,
                                len(consts))
-    outs = pl.pallas_call(
-        kernel,
-        grid=(groups, transactions),
-        in_specs=([x_spec, x_spec]
-                  + const_specs([inj_i, inj_f], memory_space=pltpu.SMEM)
-                  + const_specs(enc + consts)),
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((8, rp, lanes), jnp.dtype(np_dtype))],
-        interpret=resolve_interpret(interpret),
-        name="abft_fft",
-    )(to_blocks(xr), to_blocks(xi), inj_i, inj_f, *enc, *consts)
+    xr, xi = to_blocks(xr), to_blocks(xi)
+    with jax.named_scope("fft.kernel"):
+        outs = pl.pallas_call(
+            kernel,
+            grid=(groups, transactions),
+            in_specs=([x_spec, x_spec]
+                      + const_specs([inj_i, inj_f], memory_space=pltpu.SMEM)
+                      + const_specs(enc + consts)),
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=[pltpu.VMEM((8, rp, lanes), jnp.dtype(np_dtype))],
+            interpret=resolve_interpret(interpret),
+            name="abft_fft",
+        )(xr, xi, inj_i, inj_f, *enc, *consts)
     yr, yi, cs = from_blocks(outs[0], n), from_blocks(outs[1], n), outs[-1]
 
-    # checksums back to natural order: input sums are (n1, n2) row-major,
-    # output sums (k1, k2) with k = k1 + R*k2
-    cs = cs[:, :, :r]
-    cs = jnp.concatenate(
-        [cs[:, :4].reshape(groups, 4, n),
-         jnp.swapaxes(cs[:, 4:], -1, -2).reshape(groups, 4, n)], axis=1)
-    if per_signal:
-        s = outs[2].reshape(b, r, 4).sum(axis=1)
-        dr, di = s[:, 0] - s[:, 2], s[:, 1] - s[:, 3]
-        mag = jnp.sqrt(s[:, 0] ** 2 + s[:, 1] ** 2) + EPS
-        delta = jnp.sqrt(dr * dr + di * di) / mag
-    else:
-        delta = jnp.zeros((b,), xr.dtype)
+    with jax.named_scope("abft.decode"):
+        # checksums back to natural order: input sums are (n1, n2)
+        # row-major, output sums (k1, k2) with k = k1 + R*k2
+        cs = cs[:, :, :r]
+        cs = jnp.concatenate(
+            [cs[:, :4].reshape(groups, 4, n),
+             jnp.swapaxes(cs[:, 4:], -1, -2).reshape(groups, 4, n)], axis=1)
+        if per_signal:
+            s = outs[2].reshape(b, r, 4).sum(axis=1)
+            dr, di = s[:, 0] - s[:, 2], s[:, 1] - s[:, 3]
+            mag = jnp.sqrt(s[:, 0] ** 2 + s[:, 1] ** 2) + EPS
+            delta = jnp.sqrt(dr * dr + di * di) / mag
+        else:
+            delta = jnp.zeros((b,), xr.dtype)
     return yr, yi, delta, cs

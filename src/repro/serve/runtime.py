@@ -37,6 +37,7 @@ import dataclasses
 import threading
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.plan import FTConfig, plan_cache_info
 from repro.serve.bucketing import BucketKey, SpecBucketer
@@ -193,7 +194,12 @@ class ServeRuntime:
             if batch is None:
                 return
             try:
-                self._execute(batch)
+                # profiler spans (no-ops unless a trace is on): one batch
+                # from its taking to its last result; inside _execute, the
+                # executor call up to its async result (serve.dispatch) and
+                # the device wait and copy to the host (serve.fetch)
+                with TraceAnnotation("serve.execute"):
+                    self._execute(batch)
             except BaseException as e:
                 for r in batch.requests:
                     if not r.handle.done():
@@ -221,14 +227,18 @@ class ServeRuntime:
         inject, bs = self._build_inject(plan, batch)
         if plan.sharded:
             with self._mesh_lock:
-                y, info = serve_plan(plan, xb, op=key.op, inject=inject)
-                y = np.asarray(y)
-        elif bs is not None:
-            y, info = self._ft_with_bs(plan, xb, inject, bs)
-            y = np.asarray(y)
+                with TraceAnnotation("serve.dispatch"):
+                    y, info = serve_plan(plan, xb, op=key.op, inject=inject)
+                with TraceAnnotation("serve.fetch"):
+                    y = np.asarray(y)
         else:
-            y, info = serve_plan(plan, xb, op=key.op, inject=inject)
-            y = np.asarray(y)
+            with TraceAnnotation("serve.dispatch"):
+                if bs is not None:
+                    y, info = self._ft_with_bs(plan, xb, inject, bs)
+                else:
+                    y, info = serve_plan(plan, xb, op=key.op, inject=inject)
+            with TraceAnnotation("serve.fetch"):
+                y = np.asarray(y)
         self.telemetry.record_batch(
             key, fill=fill, slots=cfg.max_batch, pad_elems=pad,
             payload_elems=payload)

@@ -7,6 +7,7 @@ returns plain dicts safe to hand across threads (and to ``json.dumps``).
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import threading
 
@@ -17,6 +18,9 @@ __all__ = ["BucketStats", "Telemetry", "percentiles"]
 # the latency quantiles every snapshot reports, the serving counterpart of
 # the HLO-volume asserts: p50 = typical, p95/p99 = the deadline tail
 QUANTILES = (50.0, 95.0, 99.0)
+# latency and queue samples kept per bucket: the most recent ones, so memory
+# and the snapshot's percentile work stay bounded however long it serves
+SAMPLES = 4096
 
 
 def percentiles(latencies_s) -> dict:
@@ -32,6 +36,9 @@ def percentiles(latencies_s) -> dict:
 @dataclasses.dataclass
 class BucketStats:
     """Mutable per-bucket accumulator (guarded by the Telemetry lock).
+
+    ``latencies_s``/``queue_s`` hold the last :data:`SAMPLES` requests, so
+    the snapshot's percentiles describe recent traffic.
 
     ``pad_elems``/``payload_elems`` carry the bucketer's padding waste:
     a request of 1000 points served from a 1024-point bucket adds 24 to
@@ -50,8 +57,10 @@ class BucketStats:
     batch_slots: int = 0              # max_batch * batches
     pad_elems: int = 0
     payload_elems: int = 0
-    latencies_s: list = dataclasses.field(default_factory=list)
-    queue_s: list = dataclasses.field(default_factory=list)
+    latencies_s: collections.deque = dataclasses.field(
+        default_factory=lambda: collections.deque(maxlen=SAMPLES))
+    queue_s: collections.deque = dataclasses.field(
+        default_factory=lambda: collections.deque(maxlen=SAMPLES))
     ft_injected: int = 0
     ft_detected: int = 0
     ft_corrected: int = 0
@@ -144,8 +153,13 @@ class Telemetry:
             st.ft_recomputed += recomputed
 
     def snapshot(self) -> dict:
-        """``{bucket label: stats dict}`` — a point-in-time copy."""
+        """``{bucket label: stats dict}`` — a point-in-time copy. The
+        samples are copied under the lock and their percentiles taken
+        outside it, so workers recording results are not held up."""
         with self._lock:
-            return {getattr(k, "label", str(k)): st.snapshot()
-                    for k, st in sorted(self._buckets.items(),
-                                        key=lambda kv: str(kv[0]))}
+            copies = [(getattr(k, "label", str(k)), dataclasses.replace(
+                st, latencies_s=list(st.latencies_s),
+                queue_s=list(st.queue_s)))
+                for k, st in sorted(self._buckets.items(),
+                                    key=lambda kv: str(kv[0]))]
+        return {label: st.snapshot() for label, st in copies}

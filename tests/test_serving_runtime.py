@@ -6,6 +6,7 @@ Everything here runs on one CPU device — the mesh serving paths are
 covered by the saturation smoke in ``benchmarks/fft_serving.py`` (CI's
 mesh-8dev lane).
 """
+import sys
 import threading
 import time
 
@@ -13,10 +14,11 @@ import numpy as np
 import pytest
 
 from repro.core.fft import api
-from repro.serve import (BucketKey, DeadlineBatcher, Fault, QueueFullError,
-                         RequestHandle, RequestTimeoutError, RuntimeClosedError,
-                         RuntimeConfig, ServeRequest, ServeRuntime,
-                         SpecBucketer, pad_transform_shape, percentiles)
+from repro.serve import (BucketKey, BucketStats, DeadlineBatcher, Fault,
+                         QueueFullError, RequestHandle, RequestTimeoutError,
+                         RuntimeClosedError, RuntimeConfig, ServeRequest,
+                         ServeRuntime, SpecBucketer, Telemetry,
+                         pad_transform_shape, percentiles)
 
 
 @pytest.fixture(autouse=True)
@@ -338,3 +340,46 @@ def test_runtime_concurrent_submitters():
     for x, y in zip(xs, results):
         np.testing.assert_allclose(y, np.fft.fft(x, 128), rtol=2e-3,
                                    atol=2e-3)
+
+
+def test_telemetry_keeps_recent_samples():
+    # the samples are a ring of the last SAMPLES requests a bucket: counts
+    # stay exact, percentiles describe the recent requests, keys unchanged
+    from repro.serve.telemetry import SAMPLES
+    tel = Telemetry()
+    for i in range(SAMPLES + 100):
+        tel.record_done("b", latency_s=i * 1e-3, queue_s=0.5e-3)
+    st = tel._buckets["b"]
+    assert len(st.latencies_s) == len(st.queue_s) == SAMPLES
+    snap = tel.snapshot()["b"]
+    assert snap["completed"] == SAMPLES + 100
+    recent = np.arange(100, SAMPLES + 100, dtype=np.float64)
+    assert snap["p50_ms"] == pytest.approx(np.percentile(recent, 50))
+    assert snap["queue_p50_ms"] == pytest.approx(0.5)
+    assert set(snap) == set(BucketStats().snapshot())
+
+
+def test_telemetry_snapshot_under_concurrent_records():
+    # workers record while a reader snapshots: no update is lost and every
+    # snapshot is consistent (the ring never exceeds its bound)
+    from repro.serve.telemetry import SAMPLES
+    tel, per, threads = Telemetry(), 3000, 6
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        def worker():
+            for i in range(per):
+                tel.record_done("b", latency_s=1e-3, queue_s=1e-4)
+        ts = [threading.Thread(target=worker) for _ in range(threads)]
+        for t in ts:
+            t.start()
+        while any(t.is_alive() for t in ts):
+            snap = tel.snapshot().get("b")
+            assert snap is None or snap["p50_ms"] == pytest.approx(1.0)
+        for t in ts:
+            t.join(timeout=30)
+        assert not any(t.is_alive() for t in ts)
+    finally:
+        sys.setswitchinterval(old)
+    assert tel.snapshot()["b"]["completed"] == per * threads
+    assert len(tel._buckets["b"].latencies_s) == SAMPLES
