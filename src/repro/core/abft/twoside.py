@@ -52,7 +52,7 @@ class Verdict:
 
     error_score: jax.Array   # (G,) relative divergence of the e2 checksum
     flagged: jax.Array       # (G,) bool, error_score > threshold
-    location: jax.Array      # (G,) int32 global signal index (valid if flagged)
+    location: jax.Array      # (G,) int32 signal index: id_s - 1 (valid if flagged)
     correction: jax.Array    # (G, N) complex correction value (-eps)
 
 

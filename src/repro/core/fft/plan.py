@@ -26,7 +26,8 @@ import functools
 import math
 from typing import Sequence
 
-__all__ = ["Plan", "StagePlan", "make_plan", "block_radices", "PLAN_TABLE"]
+__all__ = ["Plan", "StagePlan", "make_plan", "block_radices", "io_layout",
+           "PLAN_TABLE"]
 
 # VMEM budget for a kernel's pipelined blocks (bytes). A v5e core has
 # 128 MiB of VMEM (the allocation limit the TPU compiler reports for a
@@ -37,6 +38,11 @@ VMEM_BUDGET = 8 * 1024 * 1024
 
 # TPU vreg lane width: the kernels' minor dimension (see kernels/stockham.py)
 LANES = 128
+
+# Largest signal length whose kernel reads and writes the natural (B, N)
+# planes (R = N / 128 rows of a signal per 128-row chunk, R <= 16: a chunk
+# then holds 128 / R >= 8 signals, a whole sublane tile of each).
+MAX_NATURAL_N = 16 * LANES
 
 # Largest signal length executed in a single VMEM-resident block FFT.
 MAX_BLOCK_N = 1 << 13  # 8192 complex64 = 64 KiB per signal
@@ -76,12 +82,29 @@ class Plan:
     def num_passes(self) -> int:
         return len(self.kernel_factors)
 
+    @property
+    def io_layout(self) -> str:
+        """How the block kernel of every pass reads and writes HBM."""
+        return io_layout(max(self.kernel_factors), self.bs)
+
     def describe(self) -> str:
         facs = "x".join(str(f) for f in self.kernel_factors)
         rads = ";".join(
             "*".join(str(s.radix) for s in st) for st in self.stages
         )
-        return f"Plan(N={self.n}={facs}, radices=[{rads}], bs={self.bs})"
+        return (f"Plan(N={self.n}={facs}, radices=[{rads}], bs={self.bs}, "
+                f"io={self.io_layout})")
+
+
+def io_layout(n: int, bs: int) -> str:
+    """The block kernel's HBM layout for length-n signals in tiles of bs.
+
+    ``"natural"``: the kernel reads and writes the ``(B, n)`` planes and
+    permutes in VMEM; it needs whole sublane tiles of signals (bs a multiple
+    of 8) and n <= MAX_NATURAL_N. ``"blocks"``: the planes are relaid out to
+    ``(B * R, 128)`` rows in HBM around the kernel.
+    """
+    return "natural" if n <= MAX_NATURAL_N and bs % 8 == 0 else "blocks"
 
 
 def block_radices(n: int) -> tuple[int, ...]:
@@ -144,10 +167,10 @@ def _pick_bs(n_block: int, batch: int, itemsize: int) -> int:
     # buffered; a signal shorter than the lane width still fills 128 lanes.
     per_signal = 2 * 2 * 2 * itemsize * max(n_block, LANES)
     bs = max(1, VMEM_BUDGET // max(per_signal, 1))
-    # lane alignment: prefer multiples of 8 (sublane) once available
+    # sublane alignment: a multiple of 8 signals, a batch below 8 padded up
     if bs >= 8:
         bs = (bs // 8) * 8
-    bs = min(bs, max(1, batch))
+    bs = min(bs, max(8, batch))
     # keep power-of-two-ish to divide batches evenly
     return 1 << (bs.bit_length() - 1) if bs > 0 else 1
 

@@ -86,7 +86,7 @@ def _block_fft_c(x2d, *, inverse, interpret, bs=None):
     plan = make_plan(x2d.shape[-1], batch=x2d.shape[0],
                      itemsize=xr.dtype.itemsize, inverse=inverse)
     if bs is None:
-        bs = min(plan.bs, x2d.shape[0])
+        bs = plan.bs
     xr, b0 = _pad_batch(xr, bs)
     xi, _ = _pad_batch(xi, bs)
     yr, yi = block_fft_pallas(xr, xi, plan=dataclasses.replace(plan),
@@ -327,7 +327,7 @@ def _ft_fft_local(
     xr, xi = _split(x)
     plan = make_plan(n, batch=b, itemsize=xr.dtype.itemsize)
     if bs is None:
-        bs = min(plan.bs, b)
+        bs = plan.bs
     # batches not divisible by bs are padded with zero signals (the same
     # treatment _block_fft_c applies) — zero rows contribute nothing to the
     # group checksums and their 1-based location ids lie beyond the real
@@ -349,6 +349,11 @@ def _ft_fft_local(
     sums = abft.GroupChecksums.from_packed(cs)
     verdict = abft.detect_locate(
         sums, forward=lambda c: block_fft_stages(c), threshold=threshold)
+    with jax.named_scope("abft.decode"):
+        # the kernel's location ids count from each group's first signal
+        first = jnp.arange(tiles // txn, dtype=jnp.int32) * (txn * bs)
+        verdict = dataclasses.replace(verdict,
+                                      location=verdict.location + first)
     if correct:
         y, _ = abft.apply_correction(y, verdict)
     with jax.named_scope("fft.layout"):

@@ -5,22 +5,29 @@ TPU adaptation of the paper's threadblock-level FFT (§3.1): a grid tile loads
 one "transaction" in the paper's vocabulary. Twiddle tables are precomputed
 host-side (no in-kernel trigonometry, paper §3.1 "twiddling factor table").
 
-Block layout. A length-``n`` signal is carried as an ``(R, L)`` row-major
-block with ``L = min(n, 128)`` on the lanes and ``R = n // L`` on the
-sublanes, so a tile of ``bs`` signals is a 2-D ``(bs * R, L)`` array whose
-rows are ``(signal, n1)``. The split ``(B, n) -> (B * R, L)`` happens outside
-``pallas_call``; no reshape inside the kernel splits or merges the lane
-dimension (Mosaic refuses such shape casts). The transform is the
-Cooley-Tukey split ``n = R * L`` (input ``n = L*n1 + n2``, output ``k = k1 +
-R*k2``), run over 128-row chunks:
+A length-``n`` signal is the Cooley-Tukey split ``n = R * L`` with ``L =
+min(n, 128)`` on the lanes and ``R = n // L`` rows: input ``n = L*n1 + n2``,
+output ``k = k1 + R*k2``. A tile is transformed in chunks of ``cs`` signals,
+``ch = cs * R`` rows (at most 128):
 
-1. sublane stage: ``kron(I, W_R) @ chunk`` — the radix-R DFT of every
-   signal in the chunk as one (128, 128) matmul;
+1. sublane stage: a ``(ch, ch)`` matmul that is the radix-R DFT of every
+   signal in the chunk;
 2. twiddle ``T[k1, n2]`` (elementwise);
 3. lane stage: ``chunk @ W_L`` — the radix-L DFT as a 2-D matmul.
 
-The kernel's output rows are ``(signal, k1)`` with ``k2`` on the lanes; the
-wrapper's transpose back to natural order fuses with the re/im join.
+:class:`Tiling` holds the two ways a chunk lies in HBM (``plan.io_layout``):
+
+* ``natural`` (R <= 16): the kernel reads and writes the ``(B, n)`` planes.
+  A chunk is loaded as rows ``(n1, signal)`` by lane-aligned slices of the
+  signals' row tiles, the sublane stage is ``kron(W_R, I_cs)``, and the lane
+  stage's columns are permuted so that a lane roll and select per row
+  tile and output tile put every ``X[k]`` at its natural position before
+  the store.
+* ``blocks`` (R = 32, 64, or a tile that is not whole sublane tiles): the
+  planes are relaid out to ``(B * R, L)`` rows ``(signal, n1)`` in HBM
+  around the kernel (``Tiling.to_io``, ``Tiling.from_io``), and the
+  sublane stage is ``kron(I_cs, W_R)``. There ``kron(W_R, I_cs)`` would
+  need chunks of ``8 * R`` rows, at several times the sublane stage's cost.
 
 Complex data is carried as split real/imag float arrays (TPU Pallas has no
 complex dtype). Every contraction asks for ``Precision.HIGHEST``: the MXU's
@@ -29,6 +36,7 @@ tolerance.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 
@@ -36,64 +44,200 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.fft import factors
-from repro.core.fft.plan import LANES, Plan, make_plan
+from repro.core.fft.plan import LANES, Plan, io_layout, make_plan
 
 from ._platform import resolve_interpret
 
-__all__ = ["block_fft_pallas", "block_layout", "chunk_rows", "const_specs",
-           "layout_consts", "fft_chunk", "for_each_chunk", "mxu_dot",
-           "to_blocks", "from_blocks"]
+__all__ = ["block_fft_pallas", "Tiling", "const_specs", "fft_chunk",
+           "for_each_chunk", "mxu_dot"]
 
-# rows one sublane-stage matmul covers: kron(I, W_R) is (CHUNK_ROWS, CHUNK_ROWS)
+# rows one sublane-stage matmul covers at most
 CHUNK_ROWS = 128
 PRECISION = jax.lax.Precision.HIGHEST
 
 
-def block_layout(n: int) -> tuple[int, int]:
-    """``(R, L)``: a length-n signal as R rows of L = min(n, 128) lanes."""
-    lanes = min(n, LANES)
-    return n // lanes, lanes
+def _chunk_offset(c, size):
+    return c * size if isinstance(c, int) else pl.multiple_of(c * size, size)
 
 
-def chunk_rows(rows: int) -> int:
-    """Rows per chunk for a tile of ``rows`` rows (a multiple of R, since
-    R divides both ``rows`` and CHUNK_ROWS)."""
-    return math.gcd(rows, CHUNK_ROWS)
+@dataclasses.dataclass(frozen=True)
+class Tiling:
+    """Where each element of a tile of ``bs`` length-``n`` signals lies:
+    in HBM, in a chunk's rows, and in the output."""
 
+    n: int
+    bs: int
 
-@jax.named_scope("fft.layout")
-def to_blocks(x: jax.Array) -> jax.Array:
-    """(B, n) -> (B * R, L), rows ``(signal, n1)``."""
-    b, n = x.shape
-    r, lanes = block_layout(n)
-    return x.reshape(b * r, lanes)
+    @property
+    def lanes(self) -> int:
+        return min(self.n, LANES)
 
+    @property
+    def r(self) -> int:
+        return self.n // self.lanes
 
-@jax.named_scope("fft.layout")
-def from_blocks(y: jax.Array, n: int) -> jax.Array:
-    """(B * R, L) with rows ``(signal, k1)`` -> (B, n) natural order."""
-    r, lanes = block_layout(n)
-    b = y.shape[0] // r
-    return jnp.swapaxes(y.reshape(b, r, lanes), 1, 2).reshape(b, n)
+    @property
+    def natural(self) -> bool:
+        return io_layout(self.n, self.bs) == "natural"
 
+    @property
+    def cs(self) -> int:
+        """Signals per chunk."""
+        return math.gcd(self.bs, CHUNK_ROWS // self.r)
 
-def layout_consts(n: int, ch: int, dtype=np.float32, *,
-                  inverse: bool = False) -> list[np.ndarray]:
-    """Host-side tables for one chunk of ``ch`` rows: ``[Kr, Ki, Tr, Ti,
-    Wr, Wi]`` (just ``[Wr, Wi]`` when the signal fits the lanes, R == 1).
-    ``K = kron(I, W_R)`` is the sublane stage, ``T`` its twiddle tiled to
-    the chunk, ``W = W_L`` the lane stage."""
-    r, lanes = block_layout(n)
-    w = factors.dft_matrix(lanes, inverse=inverse)
-    tail = [w.real, w.imag]
-    if r > 1:
-        k = np.kron(np.eye(ch // r), factors.dft_matrix(r, inverse=inverse))
-        t = np.tile(factors.stage_twiddle(r, lanes, inverse=inverse),
-                    (ch // r, 1))
-        tail = [k.real, k.imag, t.real, t.imag] + tail
-    return [np.ascontiguousarray(c, dtype=dtype) for c in tail]
+    @property
+    def ch(self) -> int:
+        """Rows per chunk."""
+        return self.cs * self.r
+
+    @property
+    def chunks(self) -> int:
+        return self.bs // self.cs
+
+    # -- HBM --------------------------------------------------------------
+
+    def io_shape(self, b: int) -> tuple[int, int]:
+        """The kernel's operand shape for ``b`` signals."""
+        return (b, self.n) if self.natural else (b * self.r, self.lanes)
+
+    def spec(self, tile) -> pl.BlockSpec:
+        """The tile's BlockSpec; ``tile`` maps grid indices to a tile."""
+        return pl.BlockSpec(self.io_shape(self.bs),
+                            lambda *g: (tile(*g), jnp.int32(0)))
+
+    def to_io(self, x: jax.Array) -> jax.Array:
+        """(B, n) planes as the kernel reads them; the blocks path's rows
+        are ``(signal, n1)``."""
+        if self.natural:
+            return x
+        with jax.named_scope("fft.layout"):
+            return x.reshape(-1, self.lanes)
+
+    def from_io(self, y: jax.Array) -> jax.Array:
+        """The kernel's output as (B, n) planes; the blocks path's rows are
+        ``(signal, k1)``."""
+        if self.natural:
+            return y
+        with jax.named_scope("fft.layout"):
+            return self.natural_order(y.reshape(-1, self.r, self.lanes))
+
+    # -- a chunk's rows ---------------------------------------------------
+    # rows are (signal, block) on the blocks path and (block, signal) on
+    # the natural path; a block is n1 on input and k1 (blocks) or the
+    # output's 128-lane tile (natural) after arrange
+
+    def row_signal(self, row):
+        if self.natural:
+            return row & (self.cs - 1)
+        return row >> (self.r.bit_length() - 1)
+
+    def row_block(self, row):
+        if self.natural:
+            return row >> (self.cs.bit_length() - 1)
+        return row & (self.r - 1)
+
+    def out_index(self, row, lane):
+        """The output index k of an arranged chunk's element."""
+        if self.natural:
+            return self.row_block(row) * self.lanes + lane
+        return self.row_block(row) + self.r * lane
+
+    def rows_table(self, v: np.ndarray) -> np.ndarray:
+        """An ``(R, L)`` table by (block, lane), repeated over a chunk's
+        signals in its row order."""
+        if self.natural:
+            return np.repeat(v, self.cs, 0)
+        return np.tile(v, (self.cs, 1))
+
+    def out_table(self, v: np.ndarray) -> np.ndarray:
+        """A natural-order length-n vector as an ``(R, L)`` table by the
+        arranged chunk's (block, lane)."""
+        if self.natural:
+            return v.reshape(self.r, self.lanes)
+        return v.reshape(self.lanes, self.r).T
+
+    def chunk_rows(self, c):
+        """Chunk ``c``'s rows of a ``(bs * R, ·)`` tile."""
+        return pl.ds(_chunk_offset(c, self.ch), self.ch)
+
+    def load(self, ref, c):
+        """Chunk ``c`` of a tile, ``(ch, L)``."""
+        if not self.natural:
+            return ref[self.chunk_rows(c), :]
+        rows = pl.ds(_chunk_offset(c, self.cs), self.cs)
+        parts = [ref[rows, pl.ds(j * self.lanes, self.lanes)]
+                 for j in range(self.r)]
+        return parts[0] if self.r == 1 else jnp.concatenate(parts, 0)
+
+    def store(self, ref, c, o) -> None:
+        if not self.natural:
+            ref[self.chunk_rows(c), :] = o
+            return
+        rows = pl.ds(_chunk_offset(c, self.cs), self.cs)
+        for j in range(self.r):
+            ref[rows, pl.ds(j * self.lanes, self.lanes)] = \
+                o[j * self.cs:(j + 1) * self.cs]
+
+    def arrange(self, z):
+        """The lane stage's result in store order. Natural path: row tile
+        k1 holds ``X[k1 + R*k2]`` at lane ``R*m + j`` for ``k2 = (L/R)*j +
+        m``, so output tile j's lane ``R*m + k1`` is row tile k1 rolled by
+        ``k1 - j`` lanes: ``X[L*j + R*m + k1]``."""
+        r, cs, lanes = self.r, self.cs, self.lanes
+        if not self.natural or r == 1:
+            return z
+        tiles = [z[k1 * cs:(k1 + 1) * cs] for k1 in range(r)]
+        which = jax.lax.broadcasted_iota(jnp.int32, (cs, lanes), 1) & (r - 1)
+        out = []
+        for j in range(r):
+            o = tiles[j]
+            for k1 in range(r):
+                if k1 != j:
+                    rolled = pltpu.roll(tiles[k1], jnp.int32((k1 - j) % lanes),
+                                        1)
+                    o = jnp.where(which == k1, rolled, o)
+            out.append(o)
+        return jnp.concatenate(out, 0)
+
+    def natural_order(self, a):
+        """``(..., R, L)`` by the arranged chunk's (block, lane) -> ``(...,
+        n)`` in output order."""
+        if not self.natural:
+            a = jnp.swapaxes(a, -1, -2)
+        return a.reshape(a.shape[:-2] + (self.n,))
+
+    def signal_sums(self, part):
+        """``(B * R, m)`` values by chunk row -> ``(B, m)`` sums by
+        signal."""
+        b, m = part.shape[0] // self.r, part.shape[1]
+        if self.natural:
+            part = part.reshape(b // self.cs, self.r, self.cs, m)
+            return part.sum(axis=1).reshape(b, m)
+        return part.reshape(b, self.r, m).sum(axis=1)
+
+    def consts(self, dtype=np.float32, *, inverse: bool = False
+               ) -> list[np.ndarray]:
+        """Host-side tables for one chunk: ``[Kr, Ki, Tr, Ti, Wr, Wi]``
+        (just ``[Wr, Wi]`` when the signal fits the lanes, R == 1): ``K``
+        the sublane stage, ``T`` its twiddle in the chunk's rows, ``W`` the
+        lane stage."""
+        r, lanes, cs = self.r, self.lanes, self.cs
+        w = factors.dft_matrix(lanes, inverse=inverse)
+        if self.natural:
+            lane = np.arange(lanes)
+            w = w[:, (lanes // r) * (lane % r) + lane // r]
+        tail = [w.real, w.imag]
+        if r > 1:
+            wr = factors.dft_matrix(r, inverse=inverse)
+            k = (np.kron(wr, np.eye(cs)) if self.natural
+                 else np.kron(np.eye(cs), wr))
+            t = self.rows_table(factors.stage_twiddle(r, lanes,
+                                                      inverse=inverse))
+            tail = [k.real, k.imag, t.real, t.imag] + tail
+        return [np.ascontiguousarray(c, dtype=dtype) for c in tail]
 
 
 def mxu_dot(a, b):
@@ -103,7 +247,7 @@ def mxu_dot(a, b):
 
 def fft_chunk(xr, xi, consts):
     """Transform one ``(ch, L)`` chunk (values, not refs) with the tables
-    from :func:`layout_consts`."""
+    from :meth:`Tiling.consts`."""
     if len(consts) == 6:
         kr, ki, tr, ti, wr, wi = consts
         xr, xi = (mxu_dot(kr, xr) - mxu_dot(ki, xi),
@@ -115,19 +259,24 @@ def fft_chunk(xr, xi, consts):
             mxu_dot(xr, wi) + mxu_dot(xi, wr))
 
 
-def for_each_chunk(rows: int, ch: int, body) -> None:
-    """Call ``body(row0)`` for each ``ch``-row chunk of a ``rows``-row tile
-    (an int32 loop: the kernels must compile with x64 enabled)."""
-    steps = rows // ch
+def for_each_chunk(steps: int, body) -> None:
+    """Call ``body(c)`` for each chunk ``c < steps`` of a tile (an int32
+    loop: the kernels must compile with x64 enabled), two chunks a loop
+    step where the count is even: a step is one basic block, so the second
+    chunk's matmuls can overlap the first chunk's lane rolls and selects
+    (``Tiling.arrange``), which come after its last matmul."""
     if steps == 1:
         body(0)
         return
+    per_step = 2 if steps % 2 == 0 else 1
 
     def step(c, carry):
-        body(pl.multiple_of(c * ch, ch))
+        for u in range(per_step):
+            body(c * per_step + u)
         return carry
 
-    jax.lax.fori_loop(jnp.int32(0), jnp.int32(steps), step, jnp.int32(0))
+    jax.lax.fori_loop(jnp.int32(0), jnp.int32(steps // per_step), step,
+                      jnp.int32(0))
 
 
 def const_specs(consts, **kw):
@@ -137,17 +286,17 @@ def const_specs(consts, **kw):
     return [pl.BlockSpec(c.shape, zero, **kw) for c in consts]
 
 
-def _fft_kernel(ch, n_const, xr_ref, xi_ref, *rest):
+def _fft_kernel(tiling, n_const, xr_ref, xi_ref, *rest):
     consts = [c[...] for c in rest[:n_const]]
     yr_ref, yi_ref = rest[n_const:]
 
-    def body(r0):
-        rows = pl.ds(r0, ch)
-        yr, yi = fft_chunk(xr_ref[rows, :], xi_ref[rows, :], consts)
-        yr_ref[rows, :] = yr
-        yi_ref[rows, :] = yi
+    def body(c):
+        yr, yi = fft_chunk(tiling.load(xr_ref, c), tiling.load(xi_ref, c),
+                           consts)
+        tiling.store(yr_ref, c, tiling.arrange(yr))
+        tiling.store(yi_ref, c, tiling.arrange(yi))
 
-    for_each_chunk(xr_ref.shape[0], ch, body)
+    for_each_chunk(tiling.chunks, body)
 
 
 @functools.partial(
@@ -183,26 +332,25 @@ def block_fft_pallas(
     if b % bs != 0:
         raise ValueError(f"batch {b} is not divisible by tile size bs={bs}")
 
-    r, lanes = block_layout(n)
-    rows = bs * r
-    ch = chunk_rows(rows)
+    tiling = Tiling(n, bs)
     np_dtype = np.float64 if xr.dtype == jnp.float64 else np.float32
-    consts = [jnp.asarray(c) for c in
-              layout_consts(n, ch, np_dtype, inverse=inverse)]
+    consts = [jnp.asarray(c) for c in tiling.consts(np_dtype,
+                                                    inverse=inverse)]
 
-    x_spec = pl.BlockSpec((rows, lanes), lambda i: (i, jnp.int32(0)))
-    xr, xi = to_blocks(xr), to_blocks(xi)
+    x_spec = tiling.spec(lambda i: i)
+    xr, xi = tiling.to_io(xr), tiling.to_io(xi)
     with jax.named_scope("fft.kernel"):
         yr, yi = pl.pallas_call(
-            functools.partial(_fft_kernel, ch, len(consts)),
+            functools.partial(_fft_kernel, tiling, len(consts)),
             grid=(b // bs,),
             in_specs=[x_spec, x_spec] + const_specs(consts),
             out_specs=[x_spec, x_spec],
-            out_shape=[jax.ShapeDtypeStruct((b * r, lanes), xr.dtype)] * 2,
+            out_shape=[jax.ShapeDtypeStruct(tiling.io_shape(b),
+                                            xr.dtype)] * 2,
             interpret=resolve_interpret(interpret),
             name="block_fft",
         )(xr, xi, *consts)
-    yr, yi = from_blocks(yr, n), from_blocks(yi, n)
+    yr, yi = tiling.from_io(yr), tiling.from_io(yi)
     if inverse:
         scale = jnp.asarray(1.0 / n, dtype=xr.dtype)
         yr, yi = yr * scale, yi * scale

@@ -16,11 +16,12 @@ An optional in-kernel SEU injector corrupts one output element of one tile —
 simulating a transient compute-unit fault *inside* the protected region, so
 tests exercise true end-to-end detect->locate->correct.
 
-The tile is the block kernel's ``(bs * R, L)`` layout (kernels/stockham.py):
-rows ``(signal, n1)`` on input and ``(signal, k1)`` on output. The group
-checksums sum rows with equal ``n1`` (``k1``) as a selection matmul per
-chunk; the per-signal sums come out per row and are added over each
-signal's R rows outside the kernel.
+The tile is laid out as the block kernel's (kernels/stockham.py
+:class:`Tiling`), and so are its chunks' rows. The group checksums sum the
+rows of equal block (``n1`` on input; on output the natural 128-lane tile,
+or ``k1`` on the ``blocks`` path) as a selection matmul per chunk; the
+per-signal sums come out per row and are added over each signal's R rows
+outside the kernel.
 """
 from __future__ import annotations
 
@@ -36,14 +37,12 @@ from repro.core.abft.encoding import EPS, left_encoding, left_encoding_image
 from repro.core.fft.plan import Plan, make_plan
 
 from ._platform import resolve_interpret
-from .stockham import (block_layout, chunk_rows, const_specs, fft_chunk,
-                       for_each_chunk, from_blocks, layout_consts, mxu_dot,
-                       to_blocks)
+from .stockham import Tiling, const_specs, fft_chunk, for_each_chunk, mxu_dot
 
 __all__ = ["abft_fft_pallas"]
 
 
-def _abft_kernel(r, ch, transactions, per_signal, n_const,
+def _abft_kernel(tiling, transactions, per_signal, n_const,
                  xr_ref, xi_ref, inj_i_ref, inj_f_ref, *rest):
     enc = [c[...] for c in rest[:4]] if per_signal else None
     rest = rest[4:] if per_signal else rest
@@ -54,7 +53,7 @@ def _abft_kernel(r, ch, transactions, per_signal, n_const,
 
     t = pl.program_id(1)
     tile = pl.program_id(0) * transactions + t
-    rows, lanes = xr_ref.shape
+    ch, lanes = tiling.ch, tiling.lanes
     ftype = xr_ref.dtype
 
     @pl.when(t == 0)
@@ -62,36 +61,40 @@ def _abft_kernel(r, ch, transactions, per_signal, n_const,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # selection rows for the group checksums: sel[q, j] = 1 where chunk row
-    # j holds n1 (or k1) == q; padded to a full sublane tile when R < 8
+    # j holds block q; padded to a full sublane tile when R < 8
     rp = acc_ref.shape[1]
     q_io = jax.lax.broadcasted_iota(jnp.int32, (rp, ch), 0)
     j_io = jax.lax.broadcasted_iota(jnp.int32, (rp, ch), 1)
-    sel = ((j_io & (r - 1)) == q_io).astype(ftype)
+    sel = (tiling.row_block(j_io) == q_io).astype(ftype)
     row_io = jax.lax.broadcasted_iota(jnp.int32, (ch, lanes), 0)
-    lane_io = jax.lax.broadcasted_iota(jnp.int32, (ch, lanes), 1)
-    shift = r.bit_length() - 1
+    k_io = tiling.out_index(row_io, jax.lax.broadcasted_iota(
+        jnp.int32, (ch, lanes), 1))
 
-    def body(r0):
-        sl = pl.ds(r0, ch)
-        xr = xr_ref[sl, :]
-        xi = xi_ref[sl, :]
+    def body(c):
+        xr = tiling.load(xr_ref, c)
+        xi = tiling.load(xi_ref, c)
         yr, yi = fft_chunk(xr, xi, consts)
+        yr, yi = tiling.arrange(yr), tiling.arrange(yi)
 
         # ---- simulated SEU at the compute units (inside the protected
-        # region): [row in the whole array, lane, enabled]
-        grow = tile * rows + r0
-        hit = ((inj_i_ref[0, 2] > 0) & (grow + row_io == inj_i_ref[0, 0])
-               & (lane_io == inj_i_ref[0, 1]))
+        # region): [signal, output index, enabled]
+        sig0 = tile * tiling.bs + c * tiling.cs
+        hit = ((inj_i_ref[0, 2] > 0)
+               & (sig0 + tiling.row_signal(row_io) == inj_i_ref[0, 0])
+               & (k_io == inj_i_ref[0, 1]))
         yr = yr + jnp.where(hit, inj_f_ref[0, 0], 0).astype(ftype)
         yi = yi + jnp.where(hit, inj_f_ref[0, 1], 0).astype(ftype)
-        yr_ref[sl, :] = yr
-        yi_ref[sl, :] = yi
+        tiling.store(yr_ref, c, yr)
+        tiling.store(yi_ref, c, yi)
 
         # ---- right-side checksums, accumulated across transactions.
-        # Location encoding: global 1-based signal id (paper: "each thread
-        # aggregates the product of its share and the global ID for the
-        # signal").
-        gid = (((grow + j_io) >> shift) + 1).astype(ftype)
+        # Location encoding: the signal's 1-based id within its group (the
+        # paper's global ID less the group's first; the decode adds it
+        # back). Ids up to the group size keep the e3 sums' float32
+        # rounding, which the location estimate divides by the error, far
+        # below half an id; global ids up to 2^16 did not.
+        gid = (t * tiling.bs + c * tiling.cs + tiling.row_signal(j_io)
+               + 1).astype(ftype)
         selg = sel * gid
         for q, (s, v) in enumerate(((sel, xr), (sel, xi), (selg, xr),
                                     (selg, xi), (sel, yr), (sel, yi),
@@ -101,11 +104,12 @@ def _abft_kernel(r, ch, transactions, per_signal, n_const,
         # ---- left-side per-row sums: s_in = (e1^T W) x, s_out = e1^T y
         if per_signal:
             ewr, ewi, e1r, e1i = enc
+            rows = tiling.chunk_rows(c)
             for q, p in enumerate((xr * ewr - xi * ewi, xr * ewi + xi * ewr,
                                    yr * e1r - yi * e1i, yr * e1i + yi * e1r)):
-                part_ref[sl, q:q + 1] = jnp.sum(p, axis=1, keepdims=True)
+                part_ref[rows, q:q + 1] = jnp.sum(p, axis=1, keepdims=True)
 
-    for_each_chunk(rows, ch, body)
+    for_each_chunk(tiling.chunks, body)
 
     @pl.when(t == transactions - 1)
     def _emit():
@@ -137,7 +141,8 @@ def abft_fft_pallas(
     * ``cs`` — (G, 8, N) packed right-side checksums
       [x*e2 r/i, x*e3 r/i, y*e2 r/i, y*e3 r/i] per transaction group,
     * ``inject`` — optional (6,) array [tile, row, col, enabled, eps_r, eps_i]
-      (float; integer fields rounded) simulating one SEU.
+      (float; integer fields rounded) simulating one SEU at output ``col``
+      of signal ``tile * bs + row``.
 
     ``interpret=None`` compiles on a TPU and interprets elsewhere.
     """
@@ -162,18 +167,16 @@ def abft_fft_pallas(
                          f"divisible by transactions={transactions}")
     groups = tiles // transactions
 
-    r, lanes = block_layout(n)
-    rows = bs * r
-    ch = chunk_rows(rows)
+    tiling = Tiling(n, bs)
+    r, lanes = tiling.r, tiling.lanes
     rp = max(r, 8)
     np_dtype = np.float64 if xr.dtype == jnp.float64 else np.float32
-    consts = [jnp.asarray(c) for c in layout_consts(n, ch, np_dtype)]
+    consts = [jnp.asarray(c) for c in tiling.consts(np_dtype)]
     if per_signal:
         # e1^T W indexes inputs n = L*n1 + n2; e1 indexes outputs
-        # k = k1 + R*k2, i.e. the output tile's (k1, k2) layout
         ew = left_encoding_image(n, encoding).reshape(r, lanes)
-        e1 = left_encoding(n, encoding).reshape(lanes, r).T
-        enc = [jnp.asarray(np.tile(v, (ch // r, 1)).astype(np_dtype))
+        e1 = tiling.out_table(left_encoding(n, encoding))
+        enc = [jnp.asarray(tiling.rows_table(v).astype(np_dtype))
                for v in (ew.real, ew.imag, e1.real, e1.imag)]
     else:
         enc = []
@@ -183,25 +186,24 @@ def abft_fft_pallas(
     with jax.named_scope("fft.kernel"):     # the kernel's SMEM operands
         fields = jnp.round(inject[:4]).astype(jnp.int32)
         tile, row, col, enabled = fields[0], fields[1], fields[2], fields[3]
-        inj_i = jnp.stack([(tile * bs + row) * r + col % r, col // r,
-                           enabled])[None]
+        inj_i = jnp.stack([tile * bs + row, col, enabled])[None]
         inj_f = inject[4:6].astype(np_dtype)[None]
 
-    x_spec = pl.BlockSpec(
-        (rows, lanes), lambda g, t: (g * transactions + t, jnp.int32(0)))
+    tile_of = lambda g, t: g * transactions + t  # noqa: E731
+    x_spec = tiling.spec(tile_of)
     out_specs = [x_spec, x_spec]
-    out_shape = [jax.ShapeDtypeStruct((b * r, lanes), xr.dtype)] * 2
+    out_shape = [jax.ShapeDtypeStruct(tiling.io_shape(b), xr.dtype)] * 2
     if per_signal:
         out_specs.append(pl.BlockSpec(
-            (rows, 4), lambda g, t: (g * transactions + t, jnp.int32(0))))
+            (tiling.bs * r, 4), lambda g, t: (tile_of(g, t), jnp.int32(0))))
         out_shape.append(jax.ShapeDtypeStruct((b * r, 4), xr.dtype))
     out_specs.append(pl.BlockSpec((1, 8, rp, lanes),
                                   lambda g, t: (g,) + (jnp.int32(0),) * 3))
     out_shape.append(jax.ShapeDtypeStruct((groups, 8, rp, lanes), xr.dtype))
 
-    kernel = functools.partial(_abft_kernel, r, ch, transactions, per_signal,
-                               len(consts))
-    xr, xi = to_blocks(xr), to_blocks(xi)
+    kernel = functools.partial(_abft_kernel, tiling, transactions,
+                               per_signal, len(consts))
+    xr, xi = tiling.to_io(xr), tiling.to_io(xi)
     with jax.named_scope("fft.kernel"):
         outs = pl.pallas_call(
             kernel,
@@ -215,17 +217,16 @@ def abft_fft_pallas(
             interpret=resolve_interpret(interpret),
             name="abft_fft",
         )(xr, xi, inj_i, inj_f, *enc, *consts)
-    yr, yi, cs = from_blocks(outs[0], n), from_blocks(outs[1], n), outs[-1]
+    yr, yi, cs = tiling.from_io(outs[0]), tiling.from_io(outs[1]), outs[-1]
 
     with jax.named_scope("abft.decode"):
-        # checksums back to natural order: input sums are (n1, n2)
-        # row-major, output sums (k1, k2) with k = k1 + R*k2
+        # checksums in natural order: input sums are (n1, n2) row-major,
+        # output sums by the arranged chunk's (block, lane)
         cs = cs[:, :, :r]
-        cs = jnp.concatenate(
-            [cs[:, :4].reshape(groups, 4, n),
-             jnp.swapaxes(cs[:, 4:], -1, -2).reshape(groups, 4, n)], axis=1)
+        cs = jnp.concatenate([cs[:, :4].reshape(groups, 4, n),
+                              tiling.natural_order(cs[:, 4:])], axis=1)
         if per_signal:
-            s = outs[2].reshape(b, r, 4).sum(axis=1)
+            s = tiling.signal_sums(outs[2])
             dr, di = s[:, 0] - s[:, 2], s[:, 1] - s[:, 3]
             mag = jnp.sqrt(s[:, 0] ** 2 + s[:, 1] ** 2) + EPS
             delta = jnp.sqrt(dr * dr + di * di) / mag

@@ -15,13 +15,22 @@ from repro.kernels.stockham import block_fft_pallas
 from repro.kernels.stockham_abft import abft_fft_pallas
 
 
+def _fft64(x, inverse=False):
+    """The float64 NumPy transform of ``x``."""
+    x = np.asarray(x, np.complex128)
+    return np.fft.ifft(x) if inverse else np.fft.fft(x)
+
+
+# N <= 2048 reads and writes the natural planes, 4096 and 8192 the blocks;
+# b = 24 in tiles of 8 is below a chunk of 128 / R signals for R <= 8, and
+# a tile of 64 is several chunks for R >= 4
 @pytest.mark.parametrize("n", [128, 256, 512, 1024, 2048, 4096, 8192])
-@pytest.mark.parametrize("b,bs", [(8, 8), (32, 16)])
+@pytest.mark.parametrize("b,bs", [(8, 8), (32, 16), (24, 8), (128, 64)])
 def test_block_fft_kernel_sweep(n, b, bs, crand):
     x = crand(b, n)
     yr, yi = block_fft_pallas(jnp.real(x), jnp.imag(x), bs=bs)
     got = np.asarray(yr) + 1j * np.asarray(yi)
-    want = np.asarray(ref.fft_ref(jnp.asarray(x)))
+    want = _fft64(x)
     np.testing.assert_allclose(got, want, atol=3e-5 * np.abs(want).max())
 
 
@@ -41,12 +50,25 @@ def test_block_fft_kernel_fp64(crand):
     np.testing.assert_allclose(got, want, atol=1e-12 * np.abs(want).max())
 
 
-def test_block_fft_kernel_inverse(crand):
-    x = crand(8, 512)
-    yr, yi = block_fft_pallas(jnp.real(x), jnp.imag(x), bs=8, inverse=True)
+@pytest.mark.parametrize("n", [256, 512, 1024, 2048, 4096])
+def test_block_fft_kernel_inverse(n, crand):
+    x = crand(32, n)
+    yr, yi = block_fft_pallas(jnp.real(x), jnp.imag(x), bs=16, inverse=True)
     got = np.asarray(yr) + 1j * np.asarray(yi)
-    want = np.fft.ifft(x)
+    want = _fft64(x, inverse=True)
     np.testing.assert_allclose(got, want, atol=3e-7)
+
+
+# batches padded to whole tiles of 8 signals or more: below 8 (the serving
+# runtime's batches) and beyond a tile
+@pytest.mark.parametrize("n", [256, 1024, 2048])
+@pytest.mark.parametrize("b", [3, 40])
+@pytest.mark.parametrize("inverse", [False, True], ids=["fft", "ifft"])
+def test_ops_fft_padded_batch(n, b, inverse, crand):
+    x = crand(b, n)
+    got = np.asarray(ops.ifft(x) if inverse else ops.fft(x))
+    want = _fft64(x, inverse)
+    np.testing.assert_allclose(got, want, atol=3e-5 * np.abs(want).max())
 
 
 @pytest.mark.parametrize("n", [1 << 14, 1 << 17])
@@ -75,10 +97,14 @@ def test_ops_ifft_roundtrip_multipass(crand):
 # Fused two-sided ABFT kernel
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("transactions", [1, 2, 4])
+# N = 2048 and 4096: the natural and the blocks path at their largest R
+ABFT_CASES = [(1, 512), (2, 512), (4, 512), (2, 2048), (2, 4096)]
+
+
+@pytest.mark.parametrize("transactions,n", ABFT_CASES)
 @pytest.mark.parametrize("per_signal", [True, False])
-def test_abft_fft_clean_no_false_alarm(transactions, per_signal, crand):
-    x = crand(32, 512)
+def test_abft_fft_clean_no_false_alarm(transactions, per_signal, n, crand):
+    x = crand(32, n)
     res = ops.ft_fft(x, transactions=transactions, bs=8,
                      per_signal=per_signal)
     want = np.fft.fft(x)
@@ -90,23 +116,30 @@ def test_abft_fft_clean_no_false_alarm(transactions, per_signal, crand):
         assert float(np.asarray(res.delta).max()) < 1e-4
 
 
-@pytest.mark.parametrize("transactions", [1, 2, 4])
-def test_abft_fft_detect_locate_correct(transactions, crand):
-    b, n, bs = 32, 512, 8
+@pytest.mark.parametrize("transactions,n", ABFT_CASES)
+@pytest.mark.parametrize("per_signal", [True, False])
+def test_abft_fft_detect_locate_correct(transactions, per_signal, n, crand):
+    b, bs = 32, 8
     x = crand(b, n)
-    want = np.fft.fft(x)
-    tile, row, col = 2, 5, 37
+    want = _fft64(x)
+    tile, row, col = 2, 5, 37 * n // 512
     sig = tile * bs + row
     inj = jnp.asarray([tile, row, col, 1, 40.0, 25.0], dtype=jnp.float32)
-    res = ops.ft_fft(x, transactions=transactions, bs=bs, per_signal=True,
-                     inject=inj)
+    # the SEU lands on signal tile * bs + row at natural output column col
+    raw = ops.ft_fft(x, transactions=transactions, bs=bs,
+                     per_signal=per_signal, inject=inj, correct=False)
+    err = np.abs(np.asarray(raw.y) - want)
+    assert np.unravel_index(np.argmax(err), err.shape) == (sig, col)
+    res = ops.ft_fft(x, transactions=transactions, bs=bs,
+                     per_signal=per_signal, inject=inj)
     flagged = np.asarray(res.flagged)
     loc = np.asarray(res.location)
     assert flagged.sum() == 1
     g = int(np.argmax(flagged))
     assert loc[g] == sig  # e3/e2 ratio decodes the corrupted signal
-    # per-signal (thread-level) checksum flags the same signal
-    assert int(np.argmax(np.asarray(res.delta))) == sig
+    if per_signal:
+        # per-signal (thread-level) checksum flags the same signal
+        assert int(np.argmax(np.asarray(res.delta))) == sig
     # delayed batched correction restores the output without recompute
     np.testing.assert_allclose(np.asarray(res.y), want,
                                atol=5e-5 * np.abs(want).max())
@@ -150,6 +183,22 @@ def test_abft_fft_ragged_batch(crand):
     assert int(res.corrected) == 1
     np.testing.assert_allclose(np.asarray(res.y), want,
                                atol=1e-4 * np.abs(want).max())
+
+
+@pytest.mark.parametrize("n", [256, 4096])
+def test_abft_location_ids_count_within_group(n, crand):
+    """The e3 checksums weight each signal by its 1-based id within its
+    transaction group, so their size, and the float32 rounding that the
+    location estimate divides by the error, does not grow with the batch."""
+    b, bs, t = 32, 8, 2
+    x = crand(b, n)
+    _, _, _, cs = abft_fft_pallas(jnp.real(x), jnp.imag(x), bs=bs,
+                                  transactions=t)
+    ids = np.arange(b) % (bs * t) + 1.0
+    want = (ids[:, None] * x.astype(np.complex128)).reshape(-1, bs * t, n)
+    got = np.asarray(cs[:, 2]) + 1j * np.asarray(cs[:, 3])
+    np.testing.assert_allclose(got, want.sum(axis=1),
+                               atol=1e-5 * np.abs(want.sum(axis=1)).max())
 
 
 def test_abft_multi_transaction_checksum_equivalence(crand):

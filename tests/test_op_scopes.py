@@ -84,8 +84,7 @@ def test_op_scopes_prefixes():
 
 
 # a batch that is not a multiple of the tile (32 at n = 256) pads, so the
-# padding's relayout shows beside the block relayouts XLA's CPU backend
-# fuses into the split and join
+# padding's relayout and the result's slice show under fft.layout
 SHAPE = (48, 256)
 X = jax.ShapeDtypeStruct(SHAPE, jnp.complex64)
 INJECT = jax.ShapeDtypeStruct((6,), jnp.float32)

@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from repro.analysis import hlo
+from repro.core.fft import api
 from repro.core.fft.plan import make_plan
 from repro.kernels import ops
 from repro.kernels.ft_matmul import _ft_matmul_pallas
@@ -55,11 +57,19 @@ def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("n,batch", [(1 << 10, 1 << 16), (1 << 13, 1 << 13)])
-def test_block_fft_compiles(n, batch, one_chip):
+# B * N = 2^26, the batch cells' operand: the natural path up to N = 2048
+# (R = N / 128 <= 16), the blocks path above
+KERNEL_SIZES = [(256, "natural"), (512, "natural"), (1024, "natural"),
+                (2048, "natural"), (4096, "blocks"), (8192, "blocks")]
+
+
+@pytest.mark.parametrize("n,layout", KERNEL_SIZES)
+def test_block_fft_compiles(n, layout, one_chip):
+    batch = (1 << 26) // n
+    plan = make_plan(n, batch=batch)
+    assert plan.io_layout == layout and f"io={layout}" in plan.describe()
     x = _sds((batch, n), jnp.float32, one_chip)
-    bs = make_plan(n, batch=batch).bs
-    compiled = block_fft_pallas.lower(x, x, bs=bs,
+    compiled = block_fft_pallas.lower(x, x, bs=plan.bs,
                                       interpret=False).compile()
     _assert_kernel(compiled)
 
@@ -76,15 +86,65 @@ def test_multipass_fft_compiles(n, batch, factors, one_chip):
     _assert_kernel(compiled)
 
 
+@pytest.mark.parametrize("n,layout", KERNEL_SIZES)
 @pytest.mark.parametrize("per_signal", [False, True])
-def test_abft_fft_compiles(per_signal, one_chip):
-    n, batch = 1 << 10, 1 << 16
+def test_abft_fft_compiles(per_signal, n, layout, one_chip):
+    batch = (1 << 26) // n
+    assert make_plan(n, batch=batch).io_layout == layout
     x = _sds((batch, n), jnp.float32, one_chip)
     inj = _sds((6,), jnp.float32, one_chip)
     compiled = abft_fft_pallas.lower(
         x, x, transactions=4, per_signal=per_signal, interpret=False,
         inject=inj).compile()
     _assert_kernel(compiled)
+
+
+def _operand_sized_ops(text, shape):
+    """``(opcode, custom-call target, scope)`` of each ENTRY instruction
+    whose result holds an array of ``shape``, leaving out those that only
+    name a buffer (parameters, tuples, their elements, bitcasts)."""
+    scopes = hlo.op_scopes(text)
+    dims = "[" + ",".join(map(str, shape)) + "]"
+    out = []
+    for line in text[text.index("\nENTRY"):].splitlines()[1:]:
+        m = hlo.INSTR_RE.match(line)
+        if not m or dims not in m.group(2):
+            continue
+        name, _, opcode, rest = m.groups()
+        if opcode in ("parameter", "tuple", "get-tuple-element", "bitcast"):
+            continue
+        target = hlo.TARGET_RE.search(rest)
+        out.append((opcode, target.group(1) if target else None,
+                    scopes.get(name)))
+    return out
+
+
+@pytest.mark.parametrize("ft", [False, True], ids=["c2c", "abft"])
+def test_executor_moves_no_operand_sized_layout(ft, one_chip):
+    """The batch cells' executors at (65536, 1024): the kernel reads and
+    writes the natural planes, so between the complex operand and the
+    complex result there are only the two splits, the kernel and the join
+    (and, under ABFT, the correction's in-place scatter of flagged rows)."""
+    shape = (1 << 16, 1 << 10)
+    spec = api.FFTSpec(shape=shape, dtype="complex64", interpret=False,
+                       ft=api.FTConfig() if ft else None)
+    x = _sds(shape, jnp.complex64, one_chip)
+    text = (api.plan(spec).executor_hlo(
+        x, op="ft_fft", inject=_sds((6,), jnp.float32, one_chip)) if ft
+        else api.plan(spec).executor_hlo(x))
+    ops_ = _operand_sized_ops(text, shape)
+    assert not [o for o in ops_ if o[0] in ("copy", "reshape", "transpose")]
+    assert "fft.layout" not in {scope for *_, scope in ops_}
+    core = sorted((t, s) for op, t, s in ops_ if op == "custom-call")
+    assert core == sorted([("X64SplitLow", "fft.split"),
+                           ("X64SplitHigh", "fft.split"),
+                           ("tpu_custom_call", "fft.kernel"),
+                           ("X64Combine", "fft.join")])
+    rest = [o for o in ops_ if o[0] != "custom-call"]
+    if ft:
+        assert {s for *_, s in rest} <= {"abft.correct"}
+    else:
+        assert rest == []
 
 
 def test_ft_matmul_compiles(one_chip):
