@@ -812,9 +812,13 @@ class FFTPlan(planbase.Plan):
 
     def executor_hlo(self, x, *, op: str = "fft", inject=None) -> str:
         """The compiled module text of the executor ``getattr(self, op)(x,
-        ...)`` runs on a local rank-1 C2C plan: ``_fft_impl`` for ``"fft"``,
-        ``_ft_fft_local`` for ``"ft_fft"`` (``inject`` as the caller passes
-        it; an array or a ``jax.ShapeDtypeStruct``).
+        ...)`` runs on a rank-1 C2C plan: on a local plan ``_fft_impl`` for
+        ``"fft"`` and ``_ft_fft_local`` for ``"ft_fft"`` (``inject`` as the
+        caller passes it; an array or a ``jax.ShapeDtypeStruct``); on a
+        sharded plan the pencil pipeline for ``"fft"``, where ``x`` carries
+        the operand's sharding (a sharded array, or a ``ShapeDtypeStruct``
+        with its ``sharding``), which decides the ingest relayout. For a
+        sharded plan the text is the per-device SPMD module.
 
         It lowers the jitted function the plan calls with the static
         arguments the plan binds, so on a warm compile cache it loads the
@@ -822,11 +826,22 @@ class FFTPlan(planbase.Plan):
         their profile's device ops.
         ``repro.analysis.hlo.op_scopes(plan.executor_hlo(x))`` maps each op
         of an XProf trace to its program stage (``fft.split``,
-        ``fft.layout``, ``fft.kernel``, ``fft.join``, ``abft.decode``, ...).
+        ``fft.layout``, ``fft.kernel``, ``fft.join``, ``abft.decode``,
+        ``fft.local``, ``fft.exchange``, ...).
         """
-        if self.rank != 1 or self.sharded or self.spec.real:
+        if self.rank != 1 or self.spec.real:
             raise ValueError("executor_hlo covers the local rank-1 C2C "
-                             f"executors; this is {self!r}")
+                             "executors and the sharded 'fft'; this is "
+                             f"{self!r}")
+        if self.sharded:
+            if op != "fft":
+                raise ValueError("executor_hlo covers a sharded plan's "
+                                 f"'fft' executor, not {op!r}")
+            if getattr(x, "sharding", None) is None:
+                raise ValueError("a sharded plan's executor depends on the "
+                                 "operand's sharding: pass the operand or a "
+                                 "ShapeDtypeStruct with its sharding")
+            return self._fwd.lower(x).compile().as_text()
         if op == "fft":
             lowered = self._fwd.func.lower(x, **self._fwd.keywords)
         elif op == "ft_fft":

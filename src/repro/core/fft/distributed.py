@@ -257,9 +257,10 @@ def _local_fft(z: jax.Array, inverse: bool) -> jax.Array:
     nloc = z.shape[-1]
     if nloc == 1:
         return z
-    if nloc <= MAX_BLOCK_N:
-        return block_fft_stages(z, inverse=inverse)
-    return _fft_factors(z, make_plan(nloc).kernel_factors, inverse)
+    with jax.named_scope("fft.local"):
+        if nloc <= MAX_BLOCK_N:
+            return block_fft_stages(z, inverse=inverse)
+        return _fft_factors(z, make_plan(nloc).kernel_factors, inverse)
 
 
 def _resolve_mesh(mesh, axis: str):
@@ -292,6 +293,12 @@ def _dist_fft_fn(mesh: Mesh, axis: str, inverse: bool,
     chunk i's all-to-all overlaps chunk i+1's pass-1 compute; results are
     bitwise-identical to the bulk-synchronous path (every per-row op is
     independent of the batch split).
+
+    The stages carry ``jax.named_scope``s (HLO metadata only): ``fft.local``
+    the stage DFTs, ``fft.twiddle`` the in-graph twiddle, ``fft.exchange``
+    the all-to-all, and ``fft.layout`` the swaps and reshapes, among them
+    the ingest reshape that the partitioner turns into an all-to-all when
+    the operand arrives in contiguous blocks (``shard_signals``).
     """
     shards = mesh.shape[axis]
     dsize = mesh.shape[data_axis] if data_axis else 1
@@ -302,19 +309,26 @@ def _dist_fft_fn(mesh: Mesh, axis: str, inverse: bool,
         n = shape[-1]
         plan = make_dist_plan(n, shards, axis)
         n1, n2 = plan.n1, plan.n2
-        z = x.reshape((-1, n1, n2))
+        with jax.named_scope("fft.layout"):
+            z = x.reshape((-1, n1, n2))
         bspec = _batch_spec(data_axis, z.shape[0], dsize)
 
         def pipeline(zl):
-            d = jax.lax.axis_index(axis)
             n2l = zl.shape[-1]
-            zl = jnp.swapaxes(zl, -1, -2)
-            zl = block_fft_stages(zl, inverse=inverse)   # FFT over n1
-            zl = jnp.swapaxes(zl, -1, -2)                # (B, n1, n2l)
-            zl = zl * _twiddle_block(n1, n2, (0, n1), (d * n2l, n2l),
-                                     zl.dtype, inverse)
-            zl = jax.lax.all_to_all(zl, axis, split_axis=1, concat_axis=2,
-                                    tiled=True)          # (B, n1/D, n2)
+            with jax.named_scope("fft.layout"):
+                zl = jnp.swapaxes(zl, -1, -2)
+            with jax.named_scope("fft.local"):
+                zl = block_fft_stages(zl, inverse=inverse)   # FFT over n1
+            with jax.named_scope("fft.layout"):
+                zl = jnp.swapaxes(zl, -1, -2)            # (B, n1, n2l)
+            with jax.named_scope("fft.twiddle"):
+                d = jax.lax.axis_index(axis)
+                zl = zl * _twiddle_block(n1, n2, (0, n1), (d * n2l, n2l),
+                                         zl.dtype, inverse)
+            with jax.named_scope("fft.exchange"):
+                zl = jax.lax.all_to_all(zl, axis, split_axis=1,
+                                        concat_axis=2,
+                                        tiled=True)      # (B, n1/D, n2)
             return _local_fft(zl, inverse)               # FFT over n2
 
         def body(zl):
@@ -331,16 +345,19 @@ def _dist_fft_fn(mesh: Mesh, axis: str, inverse: bool,
                         in_specs=P(bspec, None, axis),
                         out_specs=P(bspec, axis, None),
                         check_vma=False)(z)
-        if natural_order:
-            # k = k1 + n1*k2: transpose the cube to natural order. The
-            # shard axis (k1) lands strided in the flat result, so XLA
-            # materializes it with an all-gather — the unavoidable final
-            # redistribution every distributed FFT pays for natural order.
-            y = jnp.swapaxes(out, -1, -2).reshape((-1, n))
-        else:
-            # FFTW-MPI-style "transposed order": y[b, k1*N2 + k2] holds
-            # X[k1 + N1*k2]. Block-sharded over k1 — zero extra collectives.
-            y = out.reshape((-1, n))
+        with jax.named_scope("fft.layout"):
+            if natural_order:
+                # k = k1 + n1*k2: transpose the cube to natural order. The
+                # shard axis (k1) lands strided in the flat result, so XLA
+                # materializes it with an all-gather — the unavoidable
+                # final redistribution every distributed FFT pays for
+                # natural order.
+                y = jnp.swapaxes(out, -1, -2).reshape((-1, n))
+            else:
+                # FFTW-MPI-style "transposed order": y[b, k1*N2 + k2] holds
+                # X[k1 + N1*k2]. Block-sharded over k1 — zero extra
+                # collectives.
+                y = out.reshape((-1, n))
         if inverse:
             y = y / n
         return y.reshape(shape)
