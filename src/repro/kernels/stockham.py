@@ -1,4 +1,4 @@
-"""Pallas TPU block FFT kernel — two MXU stages per block, VMEM-resident.
+"""Pallas TPU block FFT kernel — two DFT stages per block, VMEM-resident.
 
 TPU adaptation of the paper's threadblock-level FFT (§3.1): a grid tile loads
 ``bs`` signals HBM->VMEM, transforms them entirely in VMEM, and stores back —
@@ -10,8 +10,8 @@ min(n, 128)`` on the lanes and ``R = n // L`` rows: input ``n = L*n1 + n2``,
 output ``k = k1 + R*k2``. A tile is transformed in chunks of ``cs`` signals,
 ``ch = cs * R`` rows (at most 128):
 
-1. sublane stage: a ``(ch, ch)`` matmul that is the radix-R DFT of every
-   signal in the chunk;
+1. sublane stage: the radix-R DFT of every signal in the chunk, in the
+   form its row order allows (below);
 2. twiddle ``T[k1, n2]`` (elementwise);
 3. lane stage: ``chunk @ W_L`` — the radix-L DFT as a 2-D matmul.
 
@@ -19,20 +19,25 @@ output ``k = k1 + R*k2``. A tile is transformed in chunks of ``cs`` signals,
 
 * ``natural`` (R <= 16): the kernel reads and writes the ``(B, n)`` planes.
   A chunk is loaded as rows ``(n1, signal)`` by lane-aligned slices of the
-  signals' row tiles, the sublane stage is ``kron(W_R, I_cs)``, and the lane
-  stage's columns are permuted so that a lane roll and select per row
-  tile and output tile put every ``X[k]`` at its natural position before
-  the store.
+  signals' row tiles: R row tiles of ``cs >= 8`` rows, one per n1. The
+  sublane stage combines whole tiles with constant scalars, so it runs on
+  the VPU as log2 R radix-2 butterfly stages (:func:`sublane_dft`), not as
+  the MXU product ``kron(W_R, I_cs) @ chunk``, 15/16 of whose multiply-adds
+  would multiply zeros. The lane stage's columns are permuted so that a
+  lane roll and select per row tile and output tile put every ``X[k]`` at
+  its natural position before the store.
 * ``blocks`` (R = 32, 64, or a tile that is not whole sublane tiles): the
   planes are relaid out to ``(B * R, L)`` rows ``(signal, n1)`` in HBM
   around the kernel (``Tiling.to_io``, ``Tiling.from_io``), and the
-  sublane stage is ``kron(I_cs, W_R)``. There ``kron(W_R, I_cs)`` would
-  need chunks of ``8 * R`` rows, at several times the sublane stage's cost.
+  sublane stage is the matmul ``kron(I_cs, W_R) @ chunk``: its DFT mixes
+  rows inside a sublane tile, which no elementwise combination of tiles
+  can do. ``kron(W_R, I_cs)`` would need chunks of ``8 * R`` rows, at
+  several times the sublane stage's cost.
 
 Complex data is carried as split real/imag float arrays (TPU Pallas has no
 complex dtype). Every contraction asks for ``Precision.HIGHEST``: the MXU's
 default f32 matmul is a single bf16 pass, too coarse for the FFT's 4e-5
-tolerance.
+tolerance. The butterflies are plain f32 (f64) adds and multiplies.
 """
 from __future__ import annotations
 
@@ -52,9 +57,9 @@ from repro.core.fft.plan import LANES, Plan, io_layout, make_plan
 from ._platform import resolve_interpret
 
 __all__ = ["block_fft_pallas", "Tiling", "const_specs", "fft_chunk",
-           "for_each_chunk", "mxu_dot"]
+           "for_each_chunk", "mxu_dot", "sublane_dft"]
 
-# rows one sublane-stage matmul covers at most
+# rows a chunk holds at most: the M of its matmuls
 CHUNK_ROWS = 128
 PRECISION = jax.lax.Precision.HIGHEST
 
@@ -220,10 +225,11 @@ class Tiling:
 
     def consts(self, dtype=np.float32, *, inverse: bool = False
                ) -> list[np.ndarray]:
-        """Host-side tables for one chunk: ``[Kr, Ki, Tr, Ti, Wr, Wi]``
-        (just ``[Wr, Wi]`` when the signal fits the lanes, R == 1): ``K``
-        the sublane stage, ``T`` its twiddle in the chunk's rows, ``W`` the
-        lane stage."""
+        """Host-side tables for one chunk: ``[Kr, Ki, Tr, Ti, Wr, Wi]`` on
+        the blocks path, ``[Tr, Ti, Wr, Wi]`` on the natural path (its
+        sublane stage is butterflies, no table) and ``[Wr, Wi]`` when the
+        signal fits the lanes (R == 1): ``K`` the sublane stage, ``T`` its
+        twiddle in the chunk's rows, ``W`` the lane stage."""
         r, lanes, cs = self.r, self.lanes, self.cs
         w = factors.dft_matrix(lanes, inverse=inverse)
         if self.natural:
@@ -231,12 +237,12 @@ class Tiling:
             w = w[:, (lanes // r) * (lane % r) + lane // r]
         tail = [w.real, w.imag]
         if r > 1:
-            wr = factors.dft_matrix(r, inverse=inverse)
-            k = (np.kron(wr, np.eye(cs)) if self.natural
-                 else np.kron(np.eye(cs), wr))
             t = self.rows_table(factors.stage_twiddle(r, lanes,
                                                       inverse=inverse))
-            tail = [k.real, k.imag, t.real, t.imag] + tail
+            tail = [t.real, t.imag] + tail
+            if not self.natural:
+                k = np.kron(np.eye(cs), factors.dft_matrix(r, inverse=inverse))
+                tail = [k.real, k.imag] + tail
         return [np.ascontiguousarray(c, dtype=dtype) for c in tail]
 
 
@@ -245,16 +251,61 @@ def mxu_dot(a, b):
     return jnp.dot(a, b, precision=PRECISION, preferred_element_type=a.dtype)
 
 
-def fft_chunk(xr, xi, consts):
+def _rotate(ar, ai, k, r, sign):
+    """``(ar + i*ai) * exp(sign * 2*pi*i * k / r)``. The twiddle is a Python
+    float: under x64 a NumPy float64 would promote f32 tiles to f64."""
+    if k == 0:
+        return ar, ai
+    if 4 * k == r:                          # exp(sign * i*pi/2) = sign * i
+        return (-ai, ar) if sign > 0 else (ai, -ar)
+    ang = sign * 2.0 * math.pi * k / r
+    c, s = math.cos(ang), math.sin(ang)
+    return ar * c - ai * s, ar * s + ai * c
+
+
+def _butterflies(tr, ti, sign):
+    """The DFT over lists of equal tiles, radix-2 decimation in time: the
+    outputs come back as a list in natural order."""
+    r = len(tr)
+    if r == 1:
+        return tr, ti
+    er, ei = _butterflies(tr[0::2], ti[0::2], sign)
+    odd_r, odd_i = _butterflies(tr[1::2], ti[1::2], sign)
+    h = r // 2
+    yr, yi = [None] * r, [None] * r
+    for k in range(h):
+        ar, ai = _rotate(odd_r[k], odd_i[k], k, r, sign)
+        yr[k], yi[k] = er[k] + ar, ei[k] + ai
+        yr[k + h], yi[k + h] = er[k] - ar, ei[k] - ai
+    return yr, yi
+
+
+def sublane_dft(xr, xi, r: int, *, inverse: bool = False):
+    """The natural path's sublane stage, ``kron(W_r, I_cs) @ x`` for a
+    ``(r * cs, L)`` chunk of rows ``(n1, signal)``: log2 r radix-2 stages of
+    elementwise butterflies over the chunk's r row tiles, on the VPU."""
+    cs = xr.shape[0] // r
+    tiles = lambda x: [x[j * cs:(j + 1) * cs] for j in range(r)]  # noqa: E731
+    yr, yi = _butterflies(tiles(xr), tiles(xi), 1.0 if inverse else -1.0)
+    return jnp.concatenate(yr, 0), jnp.concatenate(yi, 0)
+
+
+def fft_chunk(tiling, xr, xi, consts, *, inverse: bool = False):
     """Transform one ``(ch, L)`` chunk (values, not refs) with the tables
-    from :meth:`Tiling.consts`."""
-    if len(consts) == 6:
-        kr, ki, tr, ti, wr, wi = consts
-        xr, xi = (mxu_dot(kr, xr) - mxu_dot(ki, xi),
-                  mxu_dot(kr, xi) + mxu_dot(ki, xr))
-        xr, xi = xr * tr - xi * ti, xr * ti + xi * tr
-    else:
+    from :meth:`Tiling.consts`. The sublane stage follows the tiling's
+    path: butterflies over row tiles on the natural path, the ``K`` matmul
+    on the blocks path; the twiddle and lane stage are the same on both."""
+    if tiling.r == 1:
         wr, wi = consts
+    else:
+        if tiling.natural:
+            tr, ti, wr, wi = consts
+            xr, xi = sublane_dft(xr, xi, tiling.r, inverse=inverse)
+        else:
+            kr, ki, tr, ti, wr, wi = consts
+            xr, xi = (mxu_dot(kr, xr) - mxu_dot(ki, xi),
+                      mxu_dot(kr, xi) + mxu_dot(ki, xr))
+        xr, xi = xr * tr - xi * ti, xr * ti + xi * tr
     return (mxu_dot(xr, wr) - mxu_dot(xi, wi),
             mxu_dot(xr, wi) + mxu_dot(xi, wr))
 
@@ -286,13 +337,13 @@ def const_specs(consts, **kw):
     return [pl.BlockSpec(c.shape, zero, **kw) for c in consts]
 
 
-def _fft_kernel(tiling, n_const, xr_ref, xi_ref, *rest):
+def _fft_kernel(tiling, n_const, inverse, xr_ref, xi_ref, *rest):
     consts = [c[...] for c in rest[:n_const]]
     yr_ref, yi_ref = rest[n_const:]
 
     def body(c):
-        yr, yi = fft_chunk(tiling.load(xr_ref, c), tiling.load(xi_ref, c),
-                           consts)
+        yr, yi = fft_chunk(tiling, tiling.load(xr_ref, c),
+                           tiling.load(xi_ref, c), consts, inverse=inverse)
         tiling.store(yr_ref, c, tiling.arrange(yr))
         tiling.store(yi_ref, c, tiling.arrange(yi))
 
@@ -341,7 +392,7 @@ def block_fft_pallas(
     xr, xi = tiling.to_io(xr), tiling.to_io(xi)
     with jax.named_scope("fft.kernel"):
         yr, yi = pl.pallas_call(
-            functools.partial(_fft_kernel, tiling, len(consts)),
+            functools.partial(_fft_kernel, tiling, len(consts), inverse),
             grid=(b // bs,),
             in_specs=[x_spec, x_spec] + const_specs(consts),
             out_specs=[x_spec, x_spec],

@@ -73,7 +73,7 @@ def _abft_kernel(tiling, transactions, per_signal, n_const,
     def body(c):
         xr = tiling.load(xr_ref, c)
         xi = tiling.load(xi_ref, c)
-        yr, yi = fft_chunk(xr, xi, consts)
+        yr, yi = fft_chunk(tiling, xr, xi, consts)
         yr, yi = tiling.arrange(yr), tiling.arrange(yi)
 
         # ---- simulated SEU at the compute units (inside the protected

@@ -9,9 +9,11 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
+from repro.core.fft import factors
 from repro.kernels import ops, ref
-from repro.kernels.stockham import block_fft_pallas
+from repro.kernels.stockham import Tiling, block_fft_pallas, sublane_dft
 from repro.kernels.stockham_abft import abft_fft_pallas
 
 
@@ -57,6 +59,42 @@ def test_block_fft_kernel_inverse(n, crand):
     got = np.asarray(yr) + 1j * np.asarray(yi)
     want = _fft64(x, inverse=True)
     np.testing.assert_allclose(got, want, atol=3e-7)
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+@pytest.mark.parametrize("inverse", [False, True], ids=["fft", "ifft"])
+@pytest.mark.parametrize("r", [2, 4, 8, 16])
+def test_sublane_butterflies_match_dense_stage(r, inverse, dtype, rng):
+    """The natural path's butterflies over a chunk's R row tiles equal the
+    dense ``kron(W_R, I_cs)`` product, in an interpreted Pallas kernel,
+    without promoting the planes' dtype."""
+    cs = 8
+    xr, xi = rng.standard_normal((2, r * cs, 128)).astype(dtype)
+
+    def kernel(xr_ref, xi_ref, yr_ref, yi_ref):
+        yr_ref[...], yi_ref[...] = sublane_dft(xr_ref[...], xi_ref[...], r,
+                                               inverse=inverse)
+
+    out = jax.ShapeDtypeStruct(xr.shape, dtype)
+    yr, yi = pl.pallas_call(kernel, out_shape=[out, out],
+                            interpret=True)(xr, xi)
+    assert yr.dtype == yi.dtype == dtype
+    want = (np.kron(factors.dft_matrix(r, inverse=inverse), np.eye(cs))
+            @ (xr + 1j * xi))
+    got = np.asarray(yr) + 1j * np.asarray(yi)
+    tol = 4 * np.finfo(dtype).eps * np.log2(r) * np.abs(want).max()
+    np.testing.assert_allclose(got, want, atol=tol)
+
+
+# the natural path's sublane stage needs no table; R == 1 has none
+@pytest.mark.parametrize("n,bs,layout,tables", [
+    (64, 8, "natural", 2), (128, 8, "natural", 2), (1024, 8, "natural", 4),
+    (2048, 16, "natural", 4), (1024, 4, "blocks", 6), (4096, 8, "blocks", 6),
+    (8192, 32, "blocks", 6)])
+def test_tiling_consts_tables(n, bs, layout, tables):
+    tiling = Tiling(n, bs)
+    assert tiling.natural == (layout == "natural")
+    assert len(tiling.consts()) == tables
 
 
 # batches padded to whole tiles of 8 signals or more: below 8 (the serving

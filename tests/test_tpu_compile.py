@@ -11,6 +11,8 @@ The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -57,6 +59,15 @@ def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _kernel_operands(text):
+    """The operand shapes of the program's one Mosaic kernel."""
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    layouts = re.search(r"operand_layout_constraints=\{(.*?\})\}", calls[0])
+    return re.findall(r"\w+\[[\d,]*\]", layouts.group(1))
+
+
 # B * N = 2^26, the batch cells' operand: the natural path up to N = 2048
 # (R = N / 128 <= 16), the blocks path above
 KERNEL_SIZES = [(256, "natural"), (512, "natural"), (1024, "natural"),
@@ -72,6 +83,10 @@ def test_block_fft_compiles(n, layout, one_chip):
     compiled = block_fft_pallas.lower(x, x, bs=plan.bs,
                                       interpret=False).compile()
     _assert_kernel(compiled)
+    # two planes, then the tables: the natural path's sublane stage is
+    # butterflies and takes no K table
+    operands = _kernel_operands(compiled.as_text())
+    assert len(operands) == 2 + (4 if layout == "natural" else 6)
 
 
 @pytest.mark.parametrize("n,batch,factors", [
