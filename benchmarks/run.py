@@ -6,14 +6,12 @@ Prints ``name,us_per_call,derived`` CSV rows. Full (non-smoke) runs:
 from __future__ import annotations
 
 import argparse
-import sys
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="paper-scale grids (slow on CPU)")
-    ap.add_argument("--skip-roofline", action="store_true")
     args, _ = ap.parse_known_args()
     smoke = not args.full
 
@@ -43,13 +41,6 @@ def main() -> None:
     from . import fft_distributed
     fft_distributed.run(smoke=smoke)
     fft_distributed.run_mesh2d(smoke=smoke)
-
-    if not args.skip_roofline:
-        import os
-
-        from . import roofline
-        if os.path.isdir("artifacts/dryrun"):
-            roofline.run(smoke=smoke)
 
 
 if __name__ == "__main__":

@@ -1,8 +1,8 @@
-"""repro: TurboFFT-on-TPU — fault-tolerant FFT + LM training/serving framework.
+"""repro: TurboFFT-on-TPU — a fault-tolerant FFT library and serving runtime.
 
 FP64 (complex128) support is a first-class paper feature (the paper evaluates
-both FP32 and FP64), so x64 is enabled globally. All model code uses explicit
-float32/bfloat16 dtypes and is unaffected.
+both FP32 and FP64), so x64 is enabled globally. Code that wants float32 or
+complex64 names the dtype explicitly and is unaffected.
 """
 import jax
 

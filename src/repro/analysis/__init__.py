@@ -13,9 +13,9 @@ Two passes, one CI entry point (``python -m repro.analysis --strict``):
   assert-as-input-validation, unlocked mesh dispatch, frozen-field
   mutation), gated strict-on-new by a checked-in baseline.
 
-:mod:`repro.analysis.hlo` is import-light (stdlib ``re`` only) so both
-``launch.dryrun`` (which forces 512 host devices at import) and the audit
-can share one collective parser without import-order traps.
+:mod:`repro.analysis.hlo` is import-light (stdlib ``re`` only), so the
+audit, the benchmarks and the tests share one collective parser without
+import-order traps.
 """
 from repro.analysis.hlo import (CollectiveOp, parse_collectives,
                                 root_signature, summarize)

@@ -1,10 +1,9 @@
 """Collective-op parser for post-partitioning HLO text.
 
-Promoted from ``launch/dryrun.collective_bytes`` so the plan auditor, the
-dry-run pipeline, and the distributed-FFT benchmark all read one parser.
-This module imports nothing but the stdlib — in particular no ``jax`` —
-because ``launch/dryrun`` mutates ``XLA_FLAGS`` at import time and the
-auditor must be importable before jax picks a platform.
+The plan auditor, the distributed-FFT benchmark and the tests all read
+this one parser. This module imports nothing but the stdlib — in
+particular no ``jax`` — so the auditor can import it before jax picks a
+platform.
 
 The module under inspection is the per-device program (lowered with a
 ``jax.sharding.Mesh``), so every byte count here is per-device wire
@@ -119,8 +118,7 @@ def parse_collectives(hlo_text: str) -> list[CollectiveOp]:
     """All collectives in ``hlo_text``, program order, as structured
     records — kind, async-ness, per-buffer (dtype, elems), payload and
     wire bytes. This is the one classification point; every summary view
-    (:func:`summarize`, ``launch.dryrun.collective_bytes``) derives from
-    it."""
+    (:func:`summarize`) derives from it."""
     ops = []
     for line in hlo_text.splitlines():
         m = COLLECTIVE_RE.search(line)

@@ -3,8 +3,7 @@
 The paper derives its ABFT from the GEMV view of the DFT (§2.2.2): W is a
 *fixed, known* matrix, so the left encoding ``e1^T W`` is free to precompute.
 A neural-network linear layer is the same situation — W is the weight matrix,
-X the activations. This module protects ``Y = X @ W`` for every dense layer
-of the assigned architectures (threaded via ``models.layers.dense`` and the
+X the activations. This module protects ``Y = X @ W`` (through the
 ``core.gemm`` plan family):
 
     detect:  per-column   (e2^T X) W  vs  e2^T Y   over the token axis,

@@ -1009,8 +1009,9 @@ def collective_volume(n: int, batch: int, shards: int, *, itemsize: int = 8,
     the slab ABFT), so ``real=True`` with ``ft=True`` raises.
 
     ``*_wire`` entries are true link-crossing bytes; ``hlo_bytes`` is what
-    :func:`repro.launch.dryrun.collective_bytes` counts for the same program
-    (full per-device collective operand bytes, all-reduce at ring factor 2).
+    ``repro.analysis.hlo.summarize(parse_collectives(...))`` counts for the
+    same program (full per-device collective operand bytes, all-reduce at
+    ring factor 2).
     """
     if ft and groups % data_shards:
         raise ValueError(f"groups={groups} must divide over "

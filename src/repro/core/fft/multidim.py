@@ -205,7 +205,8 @@ def collective_volume_nd(shape: tuple[int, ...], batch: int, fft_shards: int,
     digit FFTs) and ``overlap_efficiency = 1 - 1/chunks``.
 
     ``*_wire`` entries are link-crossing bytes; ``hlo_bytes`` matches
-    :func:`repro.launch.dryrun.collective_bytes` on the same program.
+    ``repro.analysis.hlo.summarize(parse_collectives(...))`` on the same
+    program.
     """
     if decomp not in _DECOMPS:
         raise ValueError(f"decomp must be {'|'.join(_DECOMPS)}, got {decomp!r}")

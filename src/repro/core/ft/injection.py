@@ -104,8 +104,7 @@ class FaultSchedule:
         """(1, 5) GEMM fault descriptor ``[site, row, col, enable, eps]``
         for ``step`` (disabled if none) — the ``tile`` field addresses the
         protected-matmul *site* within a block and ``eps_re`` is the real
-        perturbation (GEMM activations are real). Feed to
-        ``Model.decode_step(inject=...)`` / ``FTContext``.
+        perturbation (GEMM activations are real).
         """
         for (s, tile, row, col, er, _ei) in self.entries:
             if s == step:

@@ -5,18 +5,13 @@ Every mesh here has ``Auto`` axes. ``jax.make_mesh`` defaults to
 reshapes and slices the FFT pipelines apply around ``shard_map``
 (``ShardingTypeError: Splitting on more than 1 axis``); with ``Auto`` axes
 GSPMD propagates those shardings as it always has.
-
-``make_production_mesh`` is a FUNCTION (not a module-level constant) so that
-importing this module never touches jax device state — the dry-run sets
-XLA_FLAGS before first jax init and only then calls it.
 """
 from __future__ import annotations
 
 import jax
 from jax.sharding import AxisType
 
-__all__ = ["make_mesh", "make_production_mesh", "make_host_mesh",
-           "make_fft_mesh"]
+__all__ = ["make_mesh", "make_fft_mesh"]
 
 
 def make_mesh(shape, axes, *, devices=None):
@@ -24,21 +19,6 @@ def make_mesh(shape, axes, *, devices=None):
     shape, axes = tuple(shape), tuple(axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
                          devices=devices)
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """16x16 single pod (256 chips) or 2x16x16 (512 chips, 2 pods)."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
-
-
-def make_host_mesh(data: int = 1, model: int = 1):
-    """Small mesh over whatever devices exist (tests / examples)."""
-    n = len(jax.devices())
-    if data * model > n:
-        data, model = n, 1
-    return make_mesh((data, model), ("data", "model"))
 
 
 def make_fft_mesh(shards: int | None = None, data: int = 1):

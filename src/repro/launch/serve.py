@@ -1,8 +1,6 @@
-"""Serving driver: batched greedy decode against the KV cache, plus a
-batched sharded-FFT endpoint backed by the distributed transform.
-
-    PYTHONPATH=src python -m repro.launch.serve --arch gemma3-1b --preset tiny \
-        --batch 4 --prompt-len 16 --gen 32
+"""Serving driver: a batched sharded-FFT endpoint backed by the
+distributed transform (``--mode fft``, the default), and the multi-tenant
+serving runtime's self-test (``--mode serve``).
 
     XLA_FLAGS=--xla_force_host_platform_device_count=4 \
     PYTHONPATH=src python -m repro.launch.serve --mode fft \
@@ -42,68 +40,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_config, get_smoke_config
-from repro.configs.base import ParallelConfig, RunConfig
-from repro.models import Model
-from repro.train import make_serve_step
-
-
-def decode(model: Model, params, prompts: jax.Array, gen: int,
-           max_len: int | None = None, schedule=None):
-    """Prefill via repeated decode steps, then generate ``gen`` tokens.
-
-    ``schedule`` is an optional :class:`~repro.core.ft.FaultSchedule`: each
-    host-loop step arms its GEMM fault descriptor
-    (:meth:`~repro.core.ft.FaultSchedule.for_step_gemm`) into the jitted
-    step — the descriptor is a traced array of fixed shape, so the armed
-    and clean steps share ONE compiled program. Returns ``(tokens,
-    FTStats)`` when a schedule is given (online ABFT telemetry summed over
-    steps), else just ``tokens``.
-    """
-    from repro.core.ft import FTStats
-
-    cfg = model.cfg
-    b, p = prompts.shape
-    max_len = max_len or (p + gen)
-    run = RunConfig(model=cfg)
-    step_fn = jax.jit(make_serve_step(model, run))
-    cache = model.init_cache(batch=b, max_len=max_len)
-    stats = FTStats.zeros()
-
-    def inj(step):
-        return None if schedule is None else schedule.for_step_gemm(step)
-
-    def fold(aux):
-        return stats.merge(FTStats(
-            detected=aux["ft_flagged"], corrected=aux["ft_corrected"],
-            max_score=aux["ft_max_score"],
-            skipped_updates=jnp.zeros((), jnp.float32)))
-
-    # teacher-forced prefill (decode-path; exercises the cache end-to-end)
-    nxt = prompts[:, :1]
-    for i in range(p):
-        tok = prompts[:, i:i + 1]
-        nxt, cache, aux = step_fn(params, cache, tok, jnp.int32(i), inj(i))
-        stats = fold(aux)
-    out = [nxt]
-    for j in range(gen - 1):
-        nxt, cache, aux = step_fn(params, cache, nxt, jnp.int32(p + j),
-                                  inj(p + j))
-        stats = fold(aux)
-        out.append(nxt)
-    toks = jnp.concatenate(out, axis=1)
-    return toks if schedule is None else (toks, stats)
-
-
-# The request-description layer lives in repro.serve.specs (shared with
-# the multi-tenant runtime, repro.serve.runtime); these re-exports keep
-# the historical launch.serve surface working.
-from repro.serve.specs import (SPEC_KEYS as _SPEC_KEYS,  # noqa: E402
-                               _ft_telemetry, _parse_bool, _parse_chunks,
-                               apply_fft_spec_arg, build_fft_spec,
-                               serve_plan)
-
-SPEC_KEYS = _SPEC_KEYS
+from repro.serve.specs import (SPEC_KEYS, _parse_chunks, apply_fft_spec_arg,
+                               build_fft_spec, serve_plan)
 
 
 def serve_fft(x, *, shards: int | None = None, data: int = 1,
@@ -284,12 +222,8 @@ def _main_serve(args):
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--mode", default="lm", choices=["lm", "fft", "serve"])
-    ap.add_argument("--arch", default="gemma3-1b")
-    ap.add_argument("--preset", default="tiny", choices=["tiny", "full"])
+    ap.add_argument("--mode", default="fft", choices=["fft", "serve"])
     ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=16)
-    ap.add_argument("--gen", type=int, default=32)
     ap.add_argument("--fft-n", type=int, default=1 << 16)
     ap.add_argument("--fft-shards", type=int, default=None)
     ap.add_argument("--fft-data", type=int, default=1,
@@ -324,7 +258,7 @@ def main():
     ap.add_argument("--fft-spec", default=None,
                     help="consolidated plan description, e.g. "
                          "'n=65536,batch=8,shards=4,data=2,ft=1,groups=4' "
-                         "(keys: " + ", ".join(sorted(_SPEC_KEYS)) + "); "
+                         "(keys: " + ", ".join(sorted(SPEC_KEYS)) + "); "
                          "overrides the individual --fft-* flags — the "
                          "worker builds ONE FFTPlan from it at startup")
     ap.add_argument("--fft-iters", type=int, default=5)
@@ -355,60 +289,16 @@ def main():
                          "spectrum, packed convolve) — ~half the C2C "
                          "collective bytes on a mesh")
     ap.add_argument("--ft", action="store_true",
-                    help="FFT mode: run the sharded two-side ABFT online. "
-                         "LM mode: protect every linear with the checked "
-                         "GEMM plan (core.gemm) and inject a demo "
-                         "FaultSchedule of SEUs that the decode must "
-                         "detect and correct online")
-    ap.add_argument("--ft-threshold", type=float, default=1e-3,
-                    help="LM-mode ABFT detection threshold (relative "
-                         "per-column checksum divergence)")
+                    help="run the two-side ABFT online (serve mode: on "
+                         "every request)")
     args = ap.parse_args()
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
 
-    if args.mode == "fft":
-        _main_fft(args)
-        return
     if args.mode == "serve":
         _main_serve(args)
-        return
-
-    cfg = (get_config if args.preset == "full" else get_smoke_config)(
-        args.arch)
-    schedule = None
-    if args.ft:
-        import dataclasses as _dc
-
-        from repro.core.ft import FaultSchedule
-
-        cfg = _dc.replace(cfg, ft=_dc.replace(
-            cfg.ft, protect_linears=True, threshold=args.ft_threshold))
-        # two SEUs the online ABFT must catch: one mid-prefill, one
-        # mid-generation — (step, site, row<batch, col, eps_re, eps_im)
-        schedule = FaultSchedule(entries=(
-            (min(2, args.prompt_len - 1), 0, args.batch - 1, 3, 275.0, 0.0),
-            (args.prompt_len + 1, 1, 0, 11, -310.0, 0.0),
-        ))
-    model = Model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    rng = np.random.default_rng(0)
-    prompts = jnp.asarray(
-        rng.integers(0, cfg.vocab_size, (args.batch, args.prompt_len)),
-        jnp.int32)
-    t0 = time.time()
-    res = decode(model, params, prompts, args.gen, schedule=schedule)
-    toks, stats = res if args.ft else (res, None)
-    dt = time.time() - t0
-    rate = args.batch * args.gen / dt
-    print(f"generated {toks.shape} in {dt:.2f}s ({rate:.1f} tok/s)")
-    if stats is not None:
-        print(f"ft: injected={schedule.num_faults} "
-              f"detected={float(stats.detected):.0f} "
-              f"corrected={float(stats.corrected):.0f} "
-              f"max_score={float(stats.max_score):.3f} "
-              f"backend={cfg.ft.gemm_backend}")
-    print(np.asarray(toks[:, :16]))
+    else:
+        _main_fft(args)
 
 
 if __name__ == "__main__":

@@ -1,16 +1,9 @@
-"""Distribution: sharding rules, compressed collectives, pipeline parallel."""
-from .sharding import (dp_axes, param_specs, batch_specs, cache_specs,
-                       shard_tree_specs, logical_rules)
-from .collectives import compress_allreduce_mean, quantize_int8, dequantize_int8
-from .pipeline import pipeline_apply
+"""Distribution of the FFT pipelines: mesh axes and partition specs."""
 from .fft_sharding import (fft_mesh_axis, infer_fft_mesh, pencil_specs,
                            shard_signals, data_mesh_axis, abft_group_layout,
                            abft_group_spec, slab_specs, pencil_nd_specs,
                            shard_grid)
 
-__all__ = ["dp_axes", "param_specs", "batch_specs", "cache_specs",
-           "shard_tree_specs", "logical_rules", "compress_allreduce_mean",
-           "quantize_int8", "dequantize_int8", "pipeline_apply",
-           "fft_mesh_axis", "infer_fft_mesh", "pencil_specs",
+__all__ = ["fft_mesh_axis", "infer_fft_mesh", "pencil_specs",
            "shard_signals", "data_mesh_axis", "abft_group_layout",
            "abft_group_spec", "slab_specs", "pencil_nd_specs", "shard_grid"]

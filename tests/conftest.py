@@ -1,5 +1,5 @@
 """Shared test fixtures: seeded rng, complex batches, per-dtype tolerances,
-host-mesh helpers, and the multi-device subprocess runner.
+the session's host-device count, and the multi-device subprocess runner.
 
 Every fixture is deterministic per test (seeds derive from the nodeid via
 crc32, not Python's salted hash), so reordering or deselecting tests never
@@ -16,6 +16,17 @@ import numpy as np
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# The in-process mesh tests (the sharded pipelines of a four-chip cell)
+# need several devices. The host (CPU) platform makes as many as this flag
+# asks, read once when the first backend initializes, so it is set here at
+# import, before any test runs. It shapes the host platform only. A run
+# whose XLA_FLAGS already names a device count keeps it; the tests' own
+# "needs >= N host devices" guards still skip on fewer.
+HOST_DEVICES_FLAG = "--xla_force_host_platform_device_count"
+if HOST_DEVICES_FLAG not in os.environ.get("XLA_FLAGS", ""):
+    os.environ["XLA_FLAGS"] = " ".join(
+        filter(None, [os.environ.get("XLA_FLAGS"), f"{HOST_DEVICES_FLAG}=8"]))
 
 # atol = ATOL[dtype] * max|reference| — the suite-wide spectrum tolerance
 # per complex dtype (c64 roundoff grows ~sqrt(log N); 4e-5 covers N = 2^20).
@@ -85,32 +96,15 @@ def assert_spectrum_close():
     return check
 
 
-@pytest.fixture
-def host_mesh():
-    """``host_mesh(*sizes, axes=names)`` over however many devices exist,
-    clamping to a 1-D single-device mesh when the request doesn't fit."""
-    import jax
-
-    from repro.launch.mesh import make_mesh
-
-    def make(*sizes, axes=("data", "model")):
-        n = len(jax.devices())
-        if int(np.prod(sizes)) > n:
-            sizes, axes = (n,), (axes[0],)
-        return make_mesh(sizes, axes)
-
-    return make
-
-
 def run_py(code: str, devices: int = 8, timeout: int = 600) -> str:
-    """Run ``code`` in a subprocess with a forced multi-device host platform
-    (the XLA device-count flag must be set before jax initializes, so it
-    cannot be applied inside the running test process). The child runs on
+    """Run ``code`` in a subprocess with ``devices`` host devices (the XLA
+    device-count flag is read before jax initializes, so a count other
+    than this session's needs its own process). The child runs on
     the CPU: an accelerator belongs to one process, and the parent may
     hold it."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["XLA_FLAGS"] = f"{HOST_DEVICES_FLAG}={devices}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=timeout)

@@ -5,9 +5,8 @@ groups are all corrected, two SEUs in one group decode as uncorrectable
 corruption by the two-side location encoding.
 
 Layout/validation/model tests run in-process everywhere. The multi-device
-matrix runs in-process when the host platform carries >= 4 devices (the CI
-8-device lane sets XLA_FLAGS=--xla_force_host_platform_device_count=8) and
-is additionally covered by consolidated subprocess tests in the slow lane,
+matrix runs in-process when the host platform carries >= 4 devices (the
+test session asks for 8 in conftest.py) and is additionally covered by consolidated subprocess tests in the slow lane,
 so local single-device tier-1 runs still exercise every scenario.
 """
 import numpy as np
@@ -104,8 +103,8 @@ def test_collective_volume_grouped():
 
 
 # ---------------------------------------------------------------------------
-# multi-device fault matrix (in-process on >= 4 host devices — the CI
-# 8-device lane — and via subprocess in the slow lane below)
+# multi-device fault matrix (in-process on >= 4 host devices, and via
+# subprocess in the slow lane below)
 # ---------------------------------------------------------------------------
 
 # One scenario catalogue drives the in-process and subprocess variants, so
@@ -199,12 +198,12 @@ _MESHES = {"1d": ("(4,)", '("fft",)'), "2d": ("(2, 2)", '("data", "fft")')}
 @pytest.mark.parametrize("meshname", sorted(_MESHES))
 @pytest.mark.parametrize("dtype", ["complex64", "complex128"])
 def test_group_fault_matrix_inprocess(meshname, dtype):
-    """The full scenario matrix, in-process (CI 8-device lane)."""
+    """The full scenario matrix, in-process."""
     import jax
 
     if len(jax.devices()) < 4:
-        pytest.skip("needs >= 4 host devices (the CI 8-device lane sets "
-                    "XLA_FLAGS=--xla_force_host_platform_device_count=8)")
+        pytest.skip("needs >= 4 host devices (tests/conftest.py asks "
+                    "for 8 unless XLA_FLAGS names a count)")
     shape, axes = _MESHES[meshname]
     p = [c for c in _matrix_params(shape, axes) if c["dtype"] == dtype][0]
     namespace = {"__name__": "__matrix__"}
@@ -229,7 +228,7 @@ _HLO_CODE = """
 import numpy as np, jax, jax.numpy as jnp
 from repro.launch.mesh import make_mesh
 from repro.core.fft import distributed as dist
-from repro.launch.dryrun import collective_bytes
+from repro.analysis.hlo import parse_collectives, summarize
 
 mesh = make_mesh((2, 2), ("data", "fft"))
 b, n, g = 8, 1 << 12, 4
@@ -237,7 +236,7 @@ x = jnp.ones((b, n), jnp.complex64)
 for nat in (False, True):
     fn = dist._ft_dist_fft_fn(mesh, "fft", 1e-4, True, nat, g, "data")
     hlo = fn.lower(x, jnp.zeros((1, 7), jnp.float32)).compile().as_text()
-    m = collective_bytes(hlo)
+    m = summarize(parse_collectives(hlo))
     # transposed order: ZERO all-gathers. natural order: exactly one, and
     # it is the fft-axis spectrum redistribution of THIS shard's batch
     # rows (b/data * n), not a batch all-gather (b * n) — model==HLO with

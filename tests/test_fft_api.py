@@ -5,8 +5,8 @@ legacy kwarg paths across {1-D, 2-D slab, 2-D pencil} x {plain, ft,
 transposed} x {c64, c128} on 1-D and 2-D host meshes, the deprecation
 shims on kernels.ops, rfft/irfft mesh routing, and FTPolicy.to_ft_config.
 
-Multi-device cases run in-process on >= 4 forced host devices (the CI fast
-lane and mesh-8dev lane both force them).
+Multi-device cases run in-process on >= 4 forced host devices
+(conftest.py asks for 8).
 """
 import dataclasses
 
@@ -390,7 +390,7 @@ def test_irfft_odd_n_direct_dft_fallback_with_mesh(rng):
 
 def test_serve_plan_reuses_one_plan(crand):
     _need(4)
-    from repro.launch.serve import build_fft_spec, serve_plan
+    from repro.serve.specs import build_fft_spec, serve_plan
     mesh = _mesh1()
     spec = build_fft_spec((8, 4096), mesh=mesh, ft=True, groups=4)
     assert spec.ft is not None and spec.ft.groups == 4
@@ -402,7 +402,7 @@ def test_serve_plan_reuses_one_plan(crand):
     np.testing.assert_array_equal(np.asarray(y1), np.asarray(y2))
     # spec strings on the CLI resolve through the same builder
     import argparse
-    from repro.launch.serve import apply_fft_spec_arg
+    from repro.serve.specs import apply_fft_spec_arg
     ns = argparse.Namespace(fft_n=0, batch=0, fft_shards=None, fft_data=1,
                             fft_dims=1, fft_rows=0, fft_cols=0, fft_op="fft",
                             fft_decomp="auto", ft=False, fft_groups=None,
@@ -417,7 +417,7 @@ def test_serve_plan_reuses_one_plan(crand):
 
 def test_build_fft_spec_op_defaults():
     _need(4)
-    from repro.launch.serve import build_fft_spec
+    from repro.serve.specs import build_fft_spec
     mesh = _mesh1()
     # order-agnostic periodogram defaults to transposed on a mesh
     assert build_fft_spec((8, 4096), mesh=mesh,

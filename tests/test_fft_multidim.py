@@ -1,9 +1,9 @@
 """Distributed multi-dimensional FFT (core.fft.multidim): slab + pencil
 equivalence vs jnp.fft.fft2/fftn, the decomposition chooser and its
 communication model, grouped ABFT on the 2-D slab pass, and the fused 2-D
-convolution. Multi-device cases run in-process on >= 4 host devices (the CI
-mesh-8dev lane) and via subprocess in the slow lane, from one shared
-scenario catalogue so the lanes cannot drift.
+convolution. Multi-device cases run in-process on >= 4 host devices
+(conftest.py asks for 8) and via subprocess in the slow lane, from one
+shared scenario catalogue so the lanes cannot drift.
 """
 import numpy as np
 import pytest
@@ -195,8 +195,8 @@ def test_fft_convolve2_local_matches_reference(rng):
 
 
 # ---------------------------------------------------------------------------
-# multi-device scenario catalogue (in-process on >= 4 devices — the CI
-# mesh-8dev lane — and via subprocess in the slow lane)
+# multi-device scenario catalogue (in-process on >= 4 devices, and via
+# subprocess in the slow lane)
 # ---------------------------------------------------------------------------
 
 _EQUIV_CODE = """
@@ -339,14 +339,14 @@ _HLO_CODE = """
 import numpy as np, jax, jax.numpy as jnp
 from repro.launch.mesh import make_mesh
 from repro.core.fft import multidim as md
-from repro.launch.dryrun import collective_bytes
+from repro.analysis.hlo import parse_collectives, summarize
 
 mesh2 = make_mesh((2, 2), ("data", "fft"))
 b, rr, cc, g = 8, 128, 256, 4
 x = jnp.ones((b, rr, cc), jnp.complex64)
 fn = md._ft_slab_fft2_fn(mesh2, "fft", 1e-4, True, g, "data")
-meas = collective_bytes(fn.lower(x, jnp.zeros((1, 7), jnp.float32))
-                        .compile().as_text())
+meas = summarize(parse_collectives(
+    fn.lower(x, jnp.zeros((1, 7), jnp.float32)).compile().as_text()))
 assert meas["count"]["all-gather"] == 0, meas["count"]
 assert meas["count"]["all-to-all"] == 1, meas["count"]
 mdl = md.collective_volume_nd((rr, cc), b, 2, ft=True, groups=g,
@@ -354,7 +354,7 @@ mdl = md.collective_volume_nd((rr, cc), b, 2, ft=True, groups=g,
 assert abs(meas["total_bytes"] / mdl["hlo_bytes"] - 1) < 1e-3, (
     meas["total_bytes"], mdl["hlo_bytes"])
 fn = md._slab_fftn_fn(mesh2, "fft", 2, False, "data")
-meas = collective_bytes(fn.lower(x).compile().as_text())
+meas = summarize(parse_collectives(fn.lower(x).compile().as_text()))
 assert meas["count"]["all-to-all"] == 1, meas["count"]
 assert meas["count"]["all-gather"] == 0, meas["count"]
 print('OK')
@@ -377,13 +377,13 @@ def _needs4():
     import jax
 
     if len(jax.devices()) < 4:
-        pytest.skip("needs >= 4 host devices (the CI mesh-8dev lane sets "
-                    "XLA_FLAGS=--xla_force_host_platform_device_count=8)")
+        pytest.skip("needs >= 4 host devices (tests/conftest.py asks "
+                    "for 8 unless XLA_FLAGS names a count)")
 
 
 def test_multidim_equivalence_inprocess():
     """Slab + pencil vs jnp.fft.fft2/fftn on 1-D and 2-D meshes, fp32 and
-    fp64, rectangular shapes, transposed order, conv2 (CI mesh-8dev lane)."""
+    fp64, rectangular shapes, transposed order, conv2."""
     _needs4()
     exec(_EQUIV_CODE, {"__name__": "__equiv__"})
 

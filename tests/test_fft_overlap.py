@@ -20,8 +20,8 @@ The contract under test, end to end:
   ``FTConfig.transactions`` or the volume model, nd pencil, slab clamp)
   and threads through serve's ``--fft-spec`` string.
 
-Multi-device cases run in-process on >= 4 forced host devices (the CI fast
-lane and mesh-8dev lane both force them).
+Multi-device cases run in-process on >= 4 forced host devices
+(conftest.py asks for 8).
 """
 import numpy as np
 import pytest
@@ -265,14 +265,14 @@ def test_chunked_ft_fault_matrix(rng, inject):
 
 def test_hlo_chunk_counts(rng):
     _need(4)
-    from repro.launch.dryrun import collective_bytes
+    from repro.analysis.hlo import parse_collectives, summarize
 
     mesh = _mesh1()
     n, b = 1 << 10, 8
     x = jnp.asarray(_crand(rng, b, n))
     for c in (1, 2, 4):
         fn = dist._dist_fft_fn(mesh, "fft", False, True, None, c)
-        m = collective_bytes(fn.lower(x).compile().as_text())
+        m = summarize(parse_collectives(fn.lower(x).compile().as_text()))
         mdl = dist.collective_volume(n, b, 4, chunks=c)
         assert m["count"].get("all-to-all", 0) == mdl["all_to_all_count"] \
             == c, (c, m["count"])
@@ -340,7 +340,7 @@ def test_plan_nd_chunks(rng):
 def test_serve_spec_arg_chunks_and_strictness():
     import argparse
 
-    from repro.launch.serve import apply_fft_spec_arg, build_fft_spec
+    from repro.serve.specs import apply_fft_spec_arg, build_fft_spec
 
     def fresh():
         return argparse.Namespace(fft_n=1 << 12, fft_batch=8, fft_shards=1,

@@ -3,8 +3,8 @@
 paths, the half-spectrum communication models, grouped two-side ABFT on the
 Hermitian-symmetric checksum layout, the packed real spectral pipeline
 (convolve / correlate / power_spectrum), and the serve threading.
-Multi-device cases run in-process on >= 4 host devices (the CI mesh-8dev
-lane) and via subprocess in the slow lane, from one shared scenario
+Multi-device cases run in-process on >= 4 host devices (conftest.py asks
+for 8) and via subprocess in the slow lane, from one shared scenario
 catalogue so the lanes cannot drift.
 """
 import numpy as np
@@ -258,7 +258,7 @@ def test_layout_specs_real_and_half_spectrum_shape():
 
 
 def test_build_fft_spec_real(rng):
-    from repro.launch.serve import build_fft_spec, serve_plan
+    from repro.serve.specs import build_fft_spec, serve_plan
     from repro.core.fft.api import plan
 
     spec = build_fft_spec((4, 32, 64), op="fft", dims=2, real=True)
@@ -281,8 +281,8 @@ def test_serve_fft_real_rejects_complex(crand):
 
 
 # ---------------------------------------------------------------------------
-# multi-device scenario catalogue (in-process on >= 4 devices — the CI
-# mesh-8dev lane — and via subprocess in the slow lane)
+# multi-device scenario catalogue (in-process on >= 4 devices, and via
+# subprocess in the slow lane)
 # ---------------------------------------------------------------------------
 
 _REAL_EQUIV_CODE = """
@@ -459,14 +459,13 @@ def _needs4():
     import jax
 
     if len(jax.devices()) < 4:
-        pytest.skip("needs >= 4 host devices (the CI mesh-8dev lane sets "
-                    "XLA_FLAGS=--xla_force_host_platform_device_count=8)")
+        pytest.skip("needs >= 4 host devices (tests/conftest.py asks "
+                    "for 8 unless XLA_FLAGS names a count)")
 
 
 def test_real_equivalence_inprocess():
     """Slab + pencil real plans vs jnp.fft.rfft2/irfft2 on 1-D and 2-D
-    meshes, fp32 and fp64, plus the packed 1-D/2-D spectral consumers
-    (CI mesh-8dev lane)."""
+    meshes, fp32 and fp64, plus the packed 1-D/2-D spectral consumers."""
     _needs4()
     exec(_REAL_EQUIV_CODE, {"__name__": "__requiv__"})
 
@@ -475,7 +474,7 @@ def test_real_equivalence_inprocess():
 @pytest.mark.parametrize("dtype", ["float32", "float64"])
 def test_real_ft_fault_matrix_inprocess(meshname, dtype):
     """k SEUs in k groups on the Hermitian half-spectrum checksum layout:
-    detected, located, and corrected in one pass (CI mesh-8dev lane)."""
+    detected, located, and corrected in one pass."""
     _needs4()
     shape, axes = _MESHES[meshname]
     p = [c for c in _ft_params(shape, axes) if c["dtype"] == dtype][0]

@@ -121,7 +121,7 @@ def test_collective_volume_psum_tracks_itemsize():
 
 def test_transposed_order_invariants_and_convolve_on_mesh():
     """(1) ifft_t(fft_t(x)) == x with exactly 2 all-to-alls and 0 all-gathers
-    (collective_bytes on the composed jit); (2) fft_convolve on the 2-D
+    (the parsed collectives of the composed jit); (2) fft_convolve on the 2-D
     data x fft mesh matches jnp.convolve and meets the same collective
     budget, with HLO bytes equal to spectral_volume's model; (3) the
     kernels.ops entry points thread natural_order through."""
@@ -131,7 +131,7 @@ from repro.launch.mesh import make_mesh
 from repro.core.fft.distributed import (distributed_fft, distributed_ifft,
                                         spectral_volume)
 from repro.core.fft import spectral
-from repro.launch.dryrun import collective_bytes
+from repro.analysis.hlo import parse_collectives, summarize
 from repro.launch.mesh import make_fft_mesh
 
 rng = np.random.default_rng(3)
@@ -148,7 +148,8 @@ assert np.abs(back - x).max() / np.abs(x).max() < 4e-5
 rt = jax.jit(lambda v: distributed_ifft(
     distributed_fft(v, mesh, natural_order=False), mesh,
     natural_order=False))
-cb = collective_bytes(rt.lower(jnp.asarray(x)).compile().as_text())
+cb = summarize(parse_collectives(
+    rt.lower(jnp.asarray(x)).compile().as_text()))
 assert cb["count"]["all-to-all"] == 2, cb["count"]
 assert cb["count"]["all-gather"] == 0, cb["count"]
 assert cb["bytes"]["all-gather"] == 0.0
@@ -187,8 +188,8 @@ aa = (rng.standard_normal((b, n)) + 1j * rng.standard_normal((b, n))
 vv = (rng.standard_normal((1, n)) + 1j * rng.standard_normal((1, n))
       ).astype(np.complex64)
 fn = spectral._spectral_pair_fn(mesh2, "fft", "data", False)
-cb2 = collective_bytes(
-    fn.lower(jnp.asarray(aa), jnp.asarray(vv)).compile().as_text())
+cb2 = summarize(parse_collectives(
+    fn.lower(jnp.asarray(aa), jnp.asarray(vv)).compile().as_text()))
 assert cb2["count"]["all-to-all"] == 2, cb2["count"]
 assert cb2["count"]["all-gather"] == 0, cb2["count"]
 mdl2 = spectral_volume(n, b, 2, kernel_batch=1, data_shards=2)

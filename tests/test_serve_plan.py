@@ -7,8 +7,7 @@ import pytest
 
 from conftest import run_py
 from repro.core.fft import api
-from repro.launch.serve import (_SPEC_KEYS, apply_fft_spec_arg,
-                                build_fft_spec, serve_plan)
+from repro.serve.specs import apply_fft_spec_arg, build_fft_spec, serve_plan
 
 
 @pytest.fixture(autouse=True)
@@ -71,7 +70,7 @@ def test_ft_telemetry_complete_grouped_and_real_mesh():
 import numpy as np, jax
 from repro.launch.mesh import make_mesh
 from repro.core.fft import api
-from repro.launch.serve import build_fft_spec, serve_plan
+from repro.serve.specs import build_fft_spec, serve_plan
 
 mesh = make_mesh((4,), ('fft',))
 rng = np.random.default_rng(0)
@@ -152,10 +151,11 @@ def test_spec_arg_serve_keys_strictness():
 
 
 def test_spec_keys_shared_with_runtime_package():
-    """launch.serve and repro.serve expose the SAME key table — the CLI
-    and the runtime must never drift on what a spec string means."""
+    """The launch.serve CLI and repro.serve read the SAME key table — the
+    CLI and the runtime must never drift on what a spec string means."""
+    from repro.launch import serve as cli
     from repro.serve import SPEC_KEYS
 
-    assert _SPEC_KEYS is SPEC_KEYS
+    assert cli.SPEC_KEYS is SPEC_KEYS
     for k in ("workers", "max_batch", "deadline_ms", "queue", "timeout_ms"):
         assert k in SPEC_KEYS
