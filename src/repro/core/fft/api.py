@@ -361,10 +361,11 @@ class FFTPlan(planbase.Plan):
         self.chunks = resolve_chunks(rows, max(1, requested)) if rows else 1
         from .distributed import _dist_fft_fn, _dist_ifft_t_fn
         self._fwd = _dist_fft_fn(self.mesh, spec.axis, False,
-                                 spec.natural_order, self.daxis, self.chunks)
+                                 spec.natural_order, self.daxis, self.chunks,
+                                 spec.interpret)
         if spec.natural_order:
             self._inv = _dist_fft_fn(self.mesh, spec.axis, True, True,
-                                     self.daxis, self.chunks)
+                                     self.daxis, self.chunks, spec.interpret)
         else:
             _dist_ifft_t_fn(self.mesh, spec.axis, self.daxis,
                             self.chunks)                       # pre-build

@@ -67,6 +67,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax import shard_map
 
+from .factors import stage_twiddle
 from .large import _fft_factors
 from .plan import MAX_BLOCK_N, make_plan
 from .stockham import block_fft_stages
@@ -237,14 +238,27 @@ def _twiddle_block(n1: int, n2: int, rows: tuple, cols: tuple, dtype,
     up to ~3e-7 absolute at N = 2^26, against ~4e-8 for a float64 table
     rounded once; the error grows with N
     (``test_twiddle_block_accuracy_at_2_26``)."""
+    rdtype = jnp.float64 if dtype == jnp.complex128 else jnp.float32
+    c, s = _twiddle_planes(n1, n2, rows, cols, rdtype, inverse)
+    return jax.lax.complex(c, s).astype(dtype)
+
+
+def _twiddle_planes(n1: int, n2: int, rows: tuple, cols: tuple, rdtype,
+                    inverse: bool) -> tuple[jax.Array, jax.Array]:
+    """The cosine and sine planes of :func:`_twiddle_block`'s table, from
+    the same iotas and angles."""
     n = n1 * n2
     k1 = rows[0] + jax.lax.broadcasted_iota(jnp.int32, (rows[1], 1), 0)
     j = cols[0] + jax.lax.broadcasted_iota(jnp.int32, (1, cols[1]), 1)
     m = (k1 * j) % n                       # exact: k1 * j < n <= 2^31
     m = jnp.where(m >= n // 2, m - n, m)   # |angle| <= pi
-    rdtype = jnp.float64 if dtype == jnp.complex128 else jnp.float32
     ang = m.astype(rdtype) * ((2.0 if inverse else -2.0) * np.pi / n)
-    return jax.lax.complex(jnp.cos(ang), jnp.sin(ang)).astype(dtype)
+    return jnp.cos(ang), jnp.sin(ang)
+
+
+def _cmul_planes(zr, zi, c, s):
+    """``(zr + i zi) * (c + i s)`` on planes."""
+    return zr * c - zi * s, zr * s + zi * c
 
 
 def _local_fft(z: jax.Array, inverse: bool) -> jax.Array:
@@ -261,6 +275,65 @@ def _local_fft(z: jax.Array, inverse: bool) -> jax.Array:
         if nloc <= MAX_BLOCK_N:
             return block_fft_stages(z, inverse=inverse)
         return _fft_factors(z, make_plan(nloc).kernel_factors, inverse)
+
+
+def _kernel_pass_planes(zr, zi, inverse: bool, interpret):
+    """One Pallas block-kernel pass over the last axis of the planes; the
+    rows pad with zeros to a whole number of the kernel's tiles."""
+    # lazy: repro.kernels imports repro.core.fft, which imports this module
+    from repro.kernels.stockham import block_fft_pallas
+
+    shape = zr.shape
+    f = shape[-1]
+    zr, zi = zr.reshape((-1, f)), zi.reshape((-1, f))
+    rows = zr.shape[0]
+    plan = make_plan(f, batch=rows, itemsize=zr.dtype.itemsize,
+                     inverse=inverse)
+    pad = (-rows) % plan.bs
+    if pad:
+        zr, zi = (jnp.pad(a, ((0, pad), (0, 0))) for a in (zr, zi))
+    yr, yi = block_fft_pallas(zr, zi, plan=plan, bs=plan.bs,
+                              inverse=inverse, interpret=interpret)
+    return yr[:rows].reshape(shape), yi[:rows].reshape(shape)
+
+
+def _factor_passes_planes(zr, zi, facs: tuple[int, ...], inverse: bool,
+                          interpret):
+    """FFT over the last axis (length ``prod(facs)``) of the planes: per
+    kernel factor a swap, a kernel pass, a swap back and the stage twiddle,
+    then the output swap — ``kernels/ops.py:_fft_multipass`` on planes."""
+    if len(facs) == 1:
+        return _kernel_pass_planes(zr, zi, inverse, interpret)
+    f1, f2 = facs[0], int(np.prod(facs[1:]))
+    lead = zr.shape[:-1]
+    with jax.named_scope("fft.local"):
+        zr, zi = (jnp.swapaxes(a.reshape(lead + (f1, f2)), -1, -2)
+                  for a in (zr, zi))                       # (..., f2, f1)
+        zr, zi = _kernel_pass_planes(zr, zi, inverse, interpret)
+        zr, zi = (jnp.swapaxes(a, -1, -2) for a in (zr, zi))  # (.., f1, f2)
+        tw = stage_twiddle(f1, f2, inverse=inverse)
+        zr, zi = _cmul_planes(zr, zi, jnp.asarray(tw.real, zr.dtype),
+                              jnp.asarray(tw.imag, zr.dtype))
+        zr, zi = _factor_passes_planes(zr, zi, facs[1:], inverse, interpret)
+        return tuple(jnp.swapaxes(a, -1, -2).reshape(lead + (f1 * f2,))
+                     for a in (zr, zi))
+
+
+def _local_fft_planes(zr, zi, inverse: bool, interpret):
+    """:func:`_local_fft` on ``(re, im)`` planes through the Pallas block
+    kernel, one HBM pass a kernel factor (``make_plan(n).kernel_factors``).
+
+    Planes in, planes out, with no complex conversion in between: on the
+    TPU each complex64 <-> planes conversion is an HBM pass of its own.
+    ``inverse`` scales by 1/n (each pass by 1/f). The kernel runs under
+    its own ``fft.kernel`` scope and the swaps and stage twiddles between
+    passes under ``fft.local``; the reshapes around a single pass take the
+    caller's scope."""
+    nloc = zr.shape[-1]
+    if nloc == 1:
+        return zr, zi
+    return _factor_passes_planes(zr, zi, make_plan(nloc).kernel_factors,
+                                 inverse, interpret)
 
 
 def _resolve_mesh(mesh, axis: str):
@@ -284,7 +357,7 @@ def _batch_spec(data_axis, b, dsize):
 @functools.lru_cache(maxsize=None)
 def _dist_fft_fn(mesh: Mesh, axis: str, inverse: bool,
                  natural_order: bool = True, data_axis: str | None = None,
-                 chunks: int = 1):
+                 chunks: int = 1, interpret: bool | None = None):
     """Build the jitted shard_map pipeline for one (mesh, axis, direction).
 
     With ``data_axis`` set, batch rows shard over it (each data shard runs
@@ -294,14 +367,26 @@ def _dist_fft_fn(mesh: Mesh, axis: str, inverse: bool,
     bitwise-identical to the bulk-synchronous path (every per-row op is
     independent of the batch split).
 
-    The stages carry ``jax.named_scope``s (HLO metadata only): ``fft.local``
-    the stage DFTs, ``fft.twiddle`` the in-graph twiddle, ``fft.exchange``
-    the all-to-all, and ``fft.layout`` the swaps and reshapes, among them
-    the ingest reshape that the partitioner turns into an all-to-all when
-    the operand arrives in contiguous blocks (``shard_signals``).
+    The operand splits into ``(re, im)`` float planes once at entry and
+    the planes combine once at the output: the stage DFTs on each side of
+    the all-to-all are Pallas block-kernel passes
+    (:func:`_local_fft_planes`; ``interpret`` as in ``block_fft_pallas``),
+    which take planes.
+
+    The stages carry ``jax.named_scope``s (HLO metadata only): ``fft.split``
+    the entry's conversion to planes, ``fft.kernel`` the kernel passes,
+    ``fft.local`` pass 2's swaps and stage twiddles, ``fft.twiddle`` the
+    in-graph twiddle, ``fft.exchange`` the all-to-all, and ``fft.layout``
+    the swaps and reshapes around pass 1, the ingest reshape that the
+    partitioner turns into an all-to-all when the operand arrives in
+    contiguous blocks (``shard_signals``), the output reshape and the
+    combine.
     """
     shards = mesh.shape[axis]
     dsize = mesh.shape[data_axis] if data_axis else 1
+
+    def swap(zr, zi):
+        return jnp.swapaxes(zr, -1, -2), jnp.swapaxes(zi, -1, -2)
 
     @jax.jit
     def run(x):  # x: (..., N) complex
@@ -313,54 +398,58 @@ def _dist_fft_fn(mesh: Mesh, axis: str, inverse: bool,
             z = x.reshape((-1, n1, n2))
         bspec = _batch_spec(data_axis, z.shape[0], dsize)
 
-        def pipeline(zl):
-            n2l = zl.shape[-1]
+        def pipeline(zr, zi):
+            n2l = zr.shape[-1]
             with jax.named_scope("fft.layout"):
-                zl = jnp.swapaxes(zl, -1, -2)
-            with jax.named_scope("fft.local"):
-                zl = block_fft_stages(zl, inverse=inverse)   # FFT over n1
-            with jax.named_scope("fft.layout"):
-                zl = jnp.swapaxes(zl, -1, -2)            # (B, n1, n2l)
+                zr, zi = swap(zr, zi)
+                zr, zi = _local_fft_planes(zr, zi, inverse,
+                                           interpret)      # FFT over n1
+                zr, zi = swap(zr, zi)                      # (B, n1, n2l)
             with jax.named_scope("fft.twiddle"):
                 d = jax.lax.axis_index(axis)
-                zl = zl * _twiddle_block(n1, n2, (0, n1), (d * n2l, n2l),
-                                         zl.dtype, inverse)
+                c, s = _twiddle_planes(n1, n2, (0, n1), (d * n2l, n2l),
+                                       zr.dtype, inverse)
+                zr, zi = _cmul_planes(zr, zi, c, s)
             with jax.named_scope("fft.exchange"):
-                zl = jax.lax.all_to_all(zl, axis, split_axis=1,
-                                        concat_axis=2,
-                                        tiled=True)      # (B, n1/D, n2)
-            return _local_fft(zl, inverse)               # FFT over n2
+                # one all-to-all of complex elements a transaction; the TPU
+                # compiler runs it as one a float plane, on the planes
+                z = jax.lax.all_to_all(jax.lax.complex(zr, zi), axis,
+                                       split_axis=1, concat_axis=2,
+                                       tiled=True)         # (B, n1/D, n2)
+            with jax.named_scope("fft.local"):
+                return _local_fft_planes(jnp.real(z), jnp.imag(z), inverse,
+                                         interpret)        # FFT over n2
 
-        def body(zl):
-            ce = resolve_chunks(zl.shape[0], chunks)
+        def body(z):
+            with jax.named_scope("fft.split"):
+                zr, zi = jnp.real(z), jnp.imag(z)
+            ce = resolve_chunks(zr.shape[0], chunks)
             if ce == 1:
-                return pipeline(zl)
+                return pipeline(zr, zi)
             # one transaction per chunk: the unrolled a2as are independent,
             # so the scheduler runs chunk i's transfer under chunk i+1's
             # pass-1 compute
-            parts = jnp.split(zl, ce, axis=0)
-            return jnp.concatenate([pipeline(p) for p in parts], axis=0)
+            parts = [pipeline(pr, pi) for pr, pi in
+                     zip(jnp.split(zr, ce, axis=0), jnp.split(zi, ce, axis=0))]
+            return tuple(jnp.concatenate(ps, axis=0) for ps in zip(*parts))
 
-        out = shard_map(body, mesh=mesh,
-                        in_specs=P(bspec, None, axis),
-                        out_specs=P(bspec, axis, None),
-                        check_vma=False)(z)
+        yr, yi = shard_map(body, mesh=mesh,
+                           in_specs=P(bspec, None, axis),
+                           out_specs=(P(bspec, axis, None),) * 2,
+                           check_vma=False)(z)
         with jax.named_scope("fft.layout"):
+            y = jax.lax.complex(yr, yi)
             if natural_order:
                 # k = k1 + n1*k2: transpose the cube to natural order. The
                 # shard axis (k1) lands strided in the flat result, so XLA
                 # materializes it with an all-gather — the unavoidable
                 # final redistribution every distributed FFT pays for
                 # natural order.
-                y = jnp.swapaxes(out, -1, -2).reshape((-1, n))
-            else:
-                # FFTW-MPI-style "transposed order": y[b, k1*N2 + k2] holds
-                # X[k1 + N1*k2]. Block-sharded over k1 — zero extra
-                # collectives.
-                y = out.reshape((-1, n))
-        if inverse:
-            y = y / n
-        return y.reshape(shape)
+                y = jnp.swapaxes(y, -1, -2)
+            # else FFTW-MPI-style "transposed order": y[b, k1*N2 + k2] holds
+            # X[k1 + N1*k2]. Block-sharded over k1 — zero extra collectives.
+            # The kernel passes scaled the inverse by 1/n already.
+            return y.reshape(shape)
 
     return run
 

@@ -259,3 +259,72 @@ assert err < 1e-11, err
 print('OK')
 """, devices=4)
     assert "OK" in out
+
+
+# ---------------------------------------------------------------------------
+# the plain pencil's stage DFTs: Pallas kernel passes on (re, im) planes
+# (in-process; tests/conftest.py asks for 8 host devices)
+# ---------------------------------------------------------------------------
+
+DIRECTIONS = pytest.mark.parametrize("inverse", [False, True],
+                                     ids=["fwd", "inv"])
+
+
+def _rel_err(y, ref):
+    return float(np.abs(y - ref).max() / np.abs(ref).max())
+
+
+def _dft64(x, inverse):
+    x = x.astype(np.complex128)
+    return np.fft.ifft(x, axis=-1) if inverse else np.fft.fft(x, axis=-1)
+
+
+@pytest.mark.parametrize("b", [8, 3])
+@DIRECTIONS
+def test_local_fft_planes_matches_float64(inverse, b, crand):
+    """The plane engine at a two-factor length, 2^14 = 128 x 128: two
+    kernel passes with the swaps and stage twiddle between them, and the
+    inverse scaled once by 1/n over the passes. At B = 3 the first pass's
+    384 rows pad to a whole number of tiles."""
+    import functools
+
+    import jax
+
+    from repro.core.fft.distributed import _local_fft_planes
+    from repro.core.fft.plan import make_plan
+
+    n = 1 << 14
+    assert make_plan(n).kernel_factors == (128, 128)
+    x = crand(b, n)
+    fn = jax.jit(functools.partial(_local_fft_planes, inverse=inverse,
+                                   interpret=None))
+    yr, yi = fn(x.real, x.imag)
+    y = np.asarray(yr) + 1j * np.asarray(yi)
+    assert _rel_err(y, _dft64(x, inverse)) < 2e-6
+
+
+@pytest.mark.parametrize("chunks", [1, 2])
+@pytest.mark.parametrize("natural", [True, False],
+                         ids=["natural", "transposed"])
+@DIRECTIONS
+def test_pencil_kernel_passes_match_float64(inverse, natural, chunks, crand):
+    """The four-device pencil at N = 2^15 = 256 x 128, B = 8, both
+    directions and orders, in one transaction and in two: transposed order
+    is ``y[k1*N2 + k2] = X[k1 + N1*k2]`` of the forward or inverse DFT."""
+    import jax
+
+    from repro.core.fft.distributed import _dist_fft_fn, make_dist_plan
+    from repro.launch.mesh import make_fft_mesh
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs >= 4 host devices (tests/conftest.py asks "
+                    "for 8 unless XLA_FLAGS names a count)")
+    b, n = 8, 1 << 15
+    p = make_dist_plan(n, 4)
+    assert (p.n1, p.n2) == (256, 128)
+    x = crand(b, n)
+    fn = _dist_fft_fn(make_fft_mesh(4), "fft", inverse, natural, None, chunks)
+    y = np.asarray(fn(x))
+    if not natural:
+        y = y.reshape(b, p.n1, p.n2).transpose(0, 2, 1).reshape(b, n)
+    assert _rel_err(y, _dft64(x, inverse)) < 2e-6

@@ -10,9 +10,15 @@ pipeline built with ``jax.named_scope`` made a no-op, and
   ``y.reshape(n1, n2).T.reshape(n)``;
 * the scopes leave the outputs bitwise as they were;
 * ``op_scopes(plan.executor_hlo(x))`` puts the exchange's all-to-all under
-  ``fft.exchange`` and the stage einsums under ``fft.local``, and maps every
-  instruction of the entry computation that carries metadata, other than
-  the parameter and the constants (which run no device op).
+  ``fft.exchange``, the Pallas block kernel's passes under ``fft.kernel``
+  (interpreted on the CPU, so their matmuls are the entry's dots), the entry's
+  conversion to planes under ``fft.split`` and pass 2's planes under
+  ``fft.local``, and maps every instruction of the entry computation that
+  carries metadata, other than the parameter and the constants (which run no
+  device op).
+
+Pass 2 here is one kernel pass; ``test_tpu_compile.py`` checks the swaps of
+a two-factor pass 2 under ``fft.local``.
 """
 import json
 
@@ -80,9 +86,11 @@ for nat in (True, False):
         "scopes": sorted(set(scopes.values())),
     }}
 
-# a copy of the pipeline with the scopes left out
+# a copy of the pipeline with the scopes left out (the kernel's own jit
+# cache would keep its traced scope)
 jax.named_scope = lambda name: contextlib.nullcontext()
 distributed._dist_fft_fn.cache_clear()
+jax.clear_caches()
 for nat in (True, False):
     fn = distributed._dist_fft_fn(mesh, "fft", False, nat, None, 1)
     text = fn.lower(sds).compile().as_text()
@@ -121,7 +129,7 @@ ORDERS = pytest.mark.parametrize("natural", [True, False],
 def test_forward_matches_float64_reference(report, natural):
     r = report[str(natural)]
     assert tuple(r["split"]) == SPLIT
-    # float32 stages at HIGHEST over 13 radix-2 levels: a few 1e-7
+    # float32 kernel passes at HIGHEST over 13 radix-2 levels: a few 1e-7
     assert r["rel_err"] < 4e-6, r["rel_err"]
 
 
@@ -138,10 +146,10 @@ def test_executor_hlo_maps_the_stages(report, natural):
     # the operand arrives in contiguous blocks: the ingest relayout is an
     # all-to-all carrying the ingest reshape's scope, then the exchange's
     assert r["a2a"] == ["fft.layout", "fft.exchange"], r["a2a"]
-    assert r["dots"] and set(r["dots"]) == {"fft.local"}, r["dots"]
+    assert r["dots"] and set(r["dots"]) == {"fft.kernel"}, r["dots"]
     assert r["unmapped"] == []
-    assert {"fft.layout", "fft.local", "fft.twiddle",
-            "fft.exchange"} <= set(r["scopes"])
+    assert {"fft.split", "fft.layout", "fft.kernel", "fft.local",
+            "fft.twiddle", "fft.exchange"} <= set(r["scopes"])
     assert r["same_text_from_array"]
 
 

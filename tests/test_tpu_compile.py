@@ -51,6 +51,14 @@ def one_chip(topo):
     jax.config.update("jax_enable_compilation_cache", prev)
 
 
+@pytest.fixture(scope="module")
+def four_chips(topo, one_chip):
+    """The described v5e:2x2 as the pencil's ``fft`` mesh (the compile
+    cache stays off, as for ``one_chip``)."""
+    from repro.launch.mesh import make_mesh
+    return make_mesh((4,), ("fft",), devices=topo.devices)
+
+
 def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
@@ -160,6 +168,44 @@ def test_executor_moves_no_operand_sized_layout(ft, one_chip):
         assert {s for *_, s in rest} <= {"abft.correct"}
     else:
         assert rest == []
+
+
+def test_pencil_stage_dfts_are_kernel_passes(four_chips):
+    """The four-chip pencil's per-device module at (4, 2^23), transposed
+    order: n1 = 256, and pass 2 over 32768 = 256 x 128 takes two kernel
+    passes. The stage DFTs are three Mosaic kernels on the planes, and the
+    planes stay planes between them: four all-to-alls, one a float plane
+    (the ingest relayout's and the exchange's), one split to planes at
+    entry and one combine at the output."""
+    from collections import Counter
+
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.core.fft.distributed import _dist_fft_fn, make_dist_plan
+
+    b, n = 4, 1 << 23
+    p = make_dist_plan(n, 4)
+    assert (p.n1, make_plan(p.n2).kernel_factors) == (256, (256, 128))
+    x = _sds((b, n), jnp.complex64,
+             NamedSharding(four_chips, PartitionSpec(None, "fft")))
+    text = _dist_fft_fn(four_chips, "fft", False, False, None, 1,
+                        False).lower(x).compile().as_text()
+    scopes = hlo.op_scopes(text)
+    ops_ = []
+    for line in text.splitlines():
+        m = hlo.INSTR_RE.match(line)
+        if m:
+            target = hlo.TARGET_RE.search(m.group(4))
+            ops_.append((m.group(3), target.group(1) if target else None,
+                         scopes.get(m.group(1))))
+    assert [s for _, t, s in ops_ if t == "tpu_custom_call"] \
+        == ["fft.kernel"] * 3
+    assert Counter(s for op, _, s in ops_ if op == "all-to-all") \
+        == {"fft.layout": 2, "fft.exchange": 2}
+    assert Counter(t for _, t, _ in ops_ if t and t.startswith("X64")) \
+        == {"X64SplitLow": 1, "X64SplitHigh": 1, "X64Combine": 1}
+    # pass 2's swaps around its first kernel pass and its output swap
+    assert Counter(s for op, _, s in ops_ if op == "copy")["fft.local"] >= 3
 
 
 def test_ft_matmul_compiles(one_chip):
